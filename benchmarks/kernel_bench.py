@@ -84,10 +84,10 @@ def run_paged(print_fn=print, *, batch=4, n_heads=8, n_kv=4, hd=32,
     pages_per = seq // page_size
     n_pages = batch * pages_per + 1
     kp = jnp.asarray(rng.randint(-127, 128,
-                                 (n_pages, page_size, n_kv, hd))
+                                 (n_pages, n_kv, page_size, hd))
                      .astype(np.int8))
     vp = jnp.asarray(rng.randint(-127, 128,
-                                 (n_pages, page_size, n_kv, hd))
+                                 (n_pages, n_kv, page_size, hd))
                      .astype(np.int8))
     bt = jnp.asarray(np.arange(1, n_pages).reshape(batch, pages_per)
                      .astype(np.int32))

@@ -123,8 +123,8 @@ _SCRIPT = textwrap.dedent("""
     # shard_map kernel through the Pallas interpreter vs the plain kernel
     B, S, H, NKV, HD, PAGE, NP, PPS = 2, 3, 8, 4, 16, 8, 12, 4
     q = jnp.asarray(rng.randn(B, S, H, HD), jnp.float32)
-    kp = jnp.asarray(rng.randint(-127, 127, (NP, PAGE, NKV, HD)), jnp.int8)
-    vp = jnp.asarray(rng.randint(-127, 127, (NP, PAGE, NKV, HD)), jnp.int8)
+    kp = jnp.asarray(rng.randint(-127, 127, (NP, NKV, PAGE, HD)), jnp.int8)
+    vp = jnp.asarray(rng.randint(-127, 127, (NP, NKV, PAGE, HD)), jnp.int8)
     bt = jnp.asarray(rng.permutation(NP)[:B * PPS].reshape(B, PPS), jnp.int32)
     lens = jnp.asarray([17, 25], jnp.int32)
     ks = jnp.asarray(np.abs(rng.randn(B, NKV)) * 0.02, jnp.float32)

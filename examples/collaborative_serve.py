@@ -58,6 +58,7 @@ import numpy as np
 from repro.core.autotune import AutoTuner
 from repro.core.costmodel import (CLOUD_TITANXP_CLASS, Channel,
                                   EDGE_TX2_CLASS)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import LMConfig, init_lm, make_graph
 from repro.serve import FaultyChannel, Request
 from repro.serve.engine import CollaborativeServingEngine, ServingEngine
@@ -206,6 +207,7 @@ def sampling_demo(params, cut_layer):
 
 def main(overload: bool = False, mesh_n: int = 1, fleet_n: int = 0,
          sample: bool = False):
+    enable_compile_cache()
     print(f"model: {CFG.name} ({CFG.param_count() / 1e6:.1f}M params)")
     mesh = None
     if mesh_n > 1:

@@ -16,10 +16,12 @@ from repro.core.collab import CollaborativeEngine
 from repro.core.costmodel import (CLOUD_TITANXP_CLASS, Channel,
                                   EDGE_TX2_CLASS)
 from repro.core.partition import partition_report
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import legacy
 
 
 def main():
+    enable_compile_cache()
     print("== AlexNet (paper Table 3 subject), ImageNet-sized input ==\n")
     graph = legacy.alexnet_graph()
     print(partition_report(graph))

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data.pipeline import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import LMConfig, init_lm, lm_loss
 from repro.train.loop import Trainer, TrainerConfig
 from repro.train.qat import make_qat_loss
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--big", action="store_true")
     ap.add_argument("--ckpt", default="artifacts/qat_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = BIG if args.big else SMALL
     if args.big:
         args.seq, args.batch = 512, 16

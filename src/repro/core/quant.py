@@ -178,10 +178,13 @@ _ste_roundtrip.defvjp(_ste_fwd, _ste_bwd)
 
 
 def fake_quant(x: jax.Array, qp: QuantParams) -> jax.Array:
-    """Quantize→dequantize with a straight-through gradient (QAT)."""
+    """Quantize→dequantize with a straight-through gradient (QAT), in
+    ``x``'s dtype: a bf16 model keeps bf16 weights and activations (the
+    f32 qparams would otherwise promote them)."""
     scale = qp._bcast(qp.scale, x.ndim)
     zp = qp._bcast(qp.zero_point, x.ndim)
-    return _ste_roundtrip(x, scale, zp, float(qp.qmin), float(qp.qmax))
+    return _ste_roundtrip(x, scale, zp, float(qp.qmin),
+                          float(qp.qmax)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

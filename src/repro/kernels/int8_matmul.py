@@ -35,8 +35,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import compiler_params
-
 _ACTS = {
     None: lambda x: x,
     "none": lambda x: x,
@@ -154,7 +152,7 @@ def int8_matmul_pallas(
             pltpu.VMEM((bm, 1), jnp.int32),
             pltpu.VMEM((1, bn), jnp.int32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_q, b_q, sa2, za2, sb2, zb2, bias2, so2, zo2)

@@ -3,8 +3,8 @@
 ``int8_matmul`` / ``quantized_dense`` handle arbitrary shapes by padding to
 block multiples (zero int8 padding is exact for the asymmetric correction —
 padded K entries contribute 0 to acc, rowsum and colsum, and the za·zb·K
-term uses the *true* K), and fall back to ``interpret=True`` automatically
-when not running on a real TPU.
+term uses the *true* K).  Off TPU they run the kernel through the Pallas
+interpreter, which is for tests only.
 """
 from __future__ import annotations
 
@@ -33,14 +33,24 @@ def _pad_to(x: jax.Array, mults: tuple[int, ...]) -> jax.Array:
     return jnp.pad(x, pads)
 
 
-def _pick_block(m: int, n: int, k: int,
-                want: tuple[int, int, int]) -> tuple[int, int, int]:
-    """Shrink the default block to the problem size (small test shapes)."""
-    def fit(dim, b):
-        while b > dim and b > 8:
+def _pick_block(m: int, n: int, k: int, want: tuple[int, int, int], *,
+                interpret: bool) -> tuple[int, int, int]:
+    """Shrink the requested block toward the problem size.
+
+    Compiled, the TPU lowering accepts only blocks whose (M, N, K) dims
+    are multiples of (32, 128, 128) for int8 operands, so a block never
+    shrinks below that and the operands are padded up to it instead.
+    The interpreter takes any block, so small test shapes keep small
+    blocks there."""
+    floors = (8, 8, 8) if interpret else (32, 128, 128)
+
+    def fit(dim, b, floor):
+        while b > dim and b > floor:
             b //= 2
-        return max(b, 8)
-    return fit(m, want[0]), fit(n, want[1]), fit(k, want[2])
+        b = max(b, floor)
+        return b if interpret else -(-b // floor) * floor
+
+    return tuple(fit(d, b, f) for d, b, f in zip((m, n, k), want, floors))
 
 
 def int8_matmul(
@@ -60,7 +70,7 @@ def int8_matmul(
         interpret = default_interpret()
     m, k = a_q.shape
     _, n = b_q.shape
-    bm, bn, bk = _pick_block(m, n, k, block)
+    bm, bn, bk = _pick_block(m, n, k, block, interpret=interpret)
     a_p = _pad_to(a_q, (bm, bk))
     b_p = _pad_to(b_q, (bk, bn))
     n_pad = b_p.shape[1]

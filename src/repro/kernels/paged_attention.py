@@ -5,10 +5,12 @@ verify, multi-token prefill).
 Serving-side counterpart of ``int8_matmul``: where that kernel keeps the
 paper's edge GEMMs at 1 B/elem, this one keeps the *KV cache* at 1 B/elem
 end-to-end.  The cache is a pool of fixed-size pages
-``[n_pages, page_size, n_kv, head_dim]`` (int8, or fp for the unquantized
+``[n_pages, n_kv, page_size, head_dim]`` (int8, or fp for the unquantized
 variant); each sequence owns a row of a block table mapping its logical
 page index to a physical page, so HBM is allocated on demand instead of
-``max_len`` up front.
+``max_len`` up front.  Pages are head-major inside: one (page, kv head)
+tile ``[page_size, head_dim]`` is contiguous, which is the block shape
+the TPU lowering accepts (its last two dims equal the array's).
 
 One attention call = one grid cell per (batch row, kv head, logical page):
 
@@ -19,7 +21,7 @@ The block table, per-row KV lengths, and per-row *query start positions*
 ride in scalar-prefetch SMEM so the K/V BlockSpec index maps can redirect
 the page DMA:
 
-  index_map = lambda b, h, p, bt, ln, qs: (bt[b, p], 0, h, 0)
+  index_map = lambda b, h, p, bt, ln, qs: (bt[b, p], h, 0, 0)
 
 The q tile carries all S query rows of the block (S=1 for plain decode):
 query i of row b sits at absolute position ``q_start[b] + i`` and may
@@ -39,17 +41,18 @@ attend KV positions ``<= q_start[b] + i`` that are also ``< lengths[b]``
 
 INT8 K/V are dequantized *inside* the QK/AV loops — per-(layer, kv-head)
 symmetric scales (optionally calibrated per slot, so shaped [B, n_kv])
-sit in SMEM and multiply the page tile right after load, so the MXU sees
+sit whole in SMEM, are read as ``ks_ref[b, h]`` and multiply the page
+tile right after load, so the MXU sees
 f32 while HBM only ever streams 1 B/elem.  GQA runs grouped: the q heads
 sharing a kv head form the sublane dim of the score tile, and a q-block
 of S tokens stacks to an (S·group, hd) tile.
 
-Off-TPU there are two fallbacks, mirroring ``ops.int8_matmul``:
-``interpret=True`` runs the very same kernel through the Pallas
-interpreter (used by the parity tests), while the serving engines default
-to ``paged_attention_ref``/``paged_attention_mq_ref`` — XLA
-implementations of identical math that are fast enough to benchmark on
-CPU.  ``paged_attention`` / ``paged_multiquery_attention`` dispatch.
+Off-TPU, for tests only, there are two stand-ins: ``interpret=True``
+runs the very same kernel through the Pallas interpreter (the parity
+tests), while the serving engines default to
+``paged_attention_ref``/``paged_attention_mq_ref`` — XLA implementations
+of identical math.  ``paged_attention`` / ``paged_multiquery_attention``
+dispatch.
 
 VMEM residency per grid cell (defaults, page_size=64, hd=128, group=8,
 S=8):
@@ -60,12 +63,15 @@ sublane) and S·group padded to 8 — the interpret/ref paths accept any
 size.
 
 Tensor-parallel: ``paged_flash_mq_sharded``/``paged_flash_decode_sharded``
-partition the pool, scales, and query heads by kv head over a mesh's
-``model`` axis via ``shard_map`` — each shard streams only its own KV
-slice and no collective is needed (attention is per-head independent;
-GQA groups never straddle shards because the guard requires
-``n_kv % tp == 0``).  ``set_tp_mesh`` installs the mesh the dispatchers
-route through on the pallas path.
+run the kernel inside ``shard_map`` over a mesh.  When ``n_kv`` divides
+the ``model`` axis, pool, scales and query heads partition by kv head,
+so each shard streams only its own KV slice and no collective is needed
+(attention is per-head independent; GQA groups never straddle shards).
+Otherwise every shard runs the whole kernel on a replicated pool.  On
+the pallas path the dispatchers route through them whenever the caller
+traces under a mesh (``jax.set_mesh`` — the serving engines' mesh phases
+do, see ``serve.scheduler._jit_phase``): GSPMD cannot partition a Mosaic
+call, so no kernel may reach it outside ``shard_map``.
 """
 from __future__ import annotations
 
@@ -77,14 +83,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.pltpu_compat import compiler_params
+from jax.sharding import PartitionSpec as P
 
 __all__ = ["paged_attention", "paged_multiquery_attention",
            "paged_flash_decode", "paged_flash_mq",
            "paged_flash_decode_sharded", "paged_flash_mq_sharded",
-           "paged_attention_ref", "paged_attention_mq_ref",
-           "set_tp_mesh"]
+           "paged_attention_ref", "paged_attention_mq_ref"]
 
 # finite stand-in for -inf: (-1e30) - (-1e30) = 0 keeps exp() NaN-free on
 # fully-masked pages, where true -inf would poison the running max
@@ -96,8 +100,8 @@ _DEFAULT_IMPL = "auto"
 
 
 def _kernel(bt_ref, len_ref, qs_ref,    # scalar-prefetch: table, lens, q0
-            q_ref, k_ref, v_ref,        # [1,1,S·G,hd], [1,P,1,hd], [1,P,1,hd]
-            ks_ref, vs_ref,             # (1,1) SMEM per-(row, kv-head) scale
+            q_ref, k_ref, v_ref,        # [1,1,S·G,hd], [1,1,P,hd], [1,1,P,hd]
+            ks_ref, vs_ref,             # [B, n_kv] SMEM per-(row, kv-head) scales
             o_ref,                      # [1,1,S·G,hd]
             m_ref, l_ref, acc_ref,      # scratch: online-softmax state
             *, page_size: int, group: int, sm_scale: float):
@@ -111,8 +115,8 @@ def _kernel(bt_ref, len_ref, qs_ref,    # scalar-prefetch: table, lens, q0
 
     # dequant on load: HBM streamed the page at 1 B/elem; the scale is a
     # scalar broadcast fused into the VPU convert
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0, 0]   # [P, hd]
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0, 0]
+    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[b, h]         # [P, hd]
+    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[b, h]
     q = q_ref[0, 0].astype(jnp.float32) * sm_scale             # [S·G, hd]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -160,7 +164,7 @@ def _norm_scales(scale: Optional[jax.Array], batch: int,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_flash_mq(
     q: jax.Array,                  # [B, S, n_heads, hd]
-    k_pages: jax.Array,            # [n_pages, page_size, n_kv, hd] int8|fp
+    k_pages: jax.Array,            # [n_pages, n_kv, page_size, hd] int8|fp
     v_pages: jax.Array,
     block_tables: jax.Array,       # [B, pages_per_seq] int32
     lengths: jax.Array,            # [B] int32, # of valid KV entries
@@ -173,7 +177,7 @@ def paged_flash_mq(
     """Flash attention of an S-query block over the paged cache →
     [B, S, n_heads, hd] (query i attends positions <= q_start + i)."""
     b, s, n_heads, hd = q.shape
-    _, page_size, n_kv, _ = k_pages.shape
+    _, n_kv, page_size, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
     group = n_heads // n_kv
     assert group * n_kv == n_heads, (n_heads, n_kv)
@@ -191,14 +195,14 @@ def paged_flash_mq(
         in_specs=[
             pl.BlockSpec((1, 1, s * group, hd),
                          lambda b_, h, p, bt, ln, qs: (b_, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b_, h, p, bt, ln, qs: (bt[b_, p], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b_, h, p, bt, ln, qs: (bt[b_, p], 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda b_, h, p, bt, ln, qs: (b_, h),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b_, h, p, bt, ln, qs: (b_, h),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b_, h, p, bt, ln, qs: (bt[b_, p], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b_, h, p, bt, ln, qs: (bt[b_, p], h, 0, 0)),
+            # whole [B, n_kv] scale arrays: a (1, 1) SMEM block is refused
+            # by the TPU lowering
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1, s * group, hd),
                                lambda b_, h, p, bt, ln, qs: (b_, h, 0, 0)),
@@ -214,7 +218,7 @@ def paged_flash_mq(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, lengths, q_start.astype(jnp.int32), qg, k_pages,
@@ -262,12 +266,15 @@ def paged_attention_mq_ref(
     so the engines' CPU benchmarks measure the same asymptotics the TPU
     kernel delivers."""
     b, s, n_heads, hd = q.shape
-    _, page_size, n_kv, _ = k_pages.shape
+    _, n_kv, page_size, _ = k_pages.shape
     group = n_heads // n_kv
     span = block_tables.shape[1] * page_size
 
-    k = k_pages[block_tables].reshape(b, span, n_kv, hd).astype(jnp.float32)
-    v = v_pages[block_tables].reshape(b, span, n_kv, hd).astype(jnp.float32)
+    def gather(pages):                  # [B, span, n_kv, hd] f32
+        g = pages[block_tables].transpose(0, 1, 3, 2, 4)
+        return g.reshape(b, span, n_kv, hd).astype(jnp.float32)
+
+    k, v = gather(k_pages), gather(v_pages)
     ks = _norm_scales(k_scale, b, n_kv)
     vs = _norm_scales(v_scale, b, n_kv)
     k = k * ks[:, None, :, None]
@@ -314,25 +321,20 @@ def paged_flash_mq_sharded(
     mesh: jax.sharding.Mesh,
     interpret: bool = False,
 ) -> jax.Array:
-    """Tensor-parallel ``paged_flash_mq`` via ``shard_map``: the page
-    pool, scales, and query heads partition by kv head over the mesh's
-    ``model`` axis, so each shard DMAs and dequantizes ONLY its own
-    1 B/elem KV slice — the whole point of TP-ing the pool: per-device
-    KV bandwidth drops by the TP degree.  Batch rides the ``data`` axis
-    when it divides.  No inter-shard collective is needed at all —
-    attention is independent per kv head, and GQA grouping survives the
-    split exactly because ``n_kv % tp == 0`` keeps each kv head's q
-    group on its shard.  Falls back to the unsharded kernel when the
-    head dim doesn't divide (guard mirrors ``launch.shardings``)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
+    """``paged_flash_mq`` inside ``shard_map`` over ``mesh``, the only
+    way a Mosaic call may run on a mesh (GSPMD cannot partition one).
+    When ``n_kv`` divides the ``model`` axis, the page pool, scales and
+    query heads partition by kv head, so each shard DMAs and dequantizes
+    ONLY its own 1 B/elem KV slice — per-device KV bandwidth drops by
+    the TP degree, and no inter-shard collective is needed (attention is
+    independent per kv head; each kv head's q group stays on its shard).
+    Otherwise (guard mirrors ``launch.shardings``) the heads replicate
+    and every shard runs the whole kernel.  Batch rides the ``data``
+    axis when it divides."""
     b, s, n_heads, hd = q.shape
-    n_kv = k_pages.shape[2]
+    n_kv = k_pages.shape[1]
     tp = int(mesh.shape["model"]) if "model" in mesh.axis_names else 1
-    if tp == 1 or n_kv % tp != 0:
-        return paged_flash_mq(q, k_pages, v_pages, block_tables, lengths,
-                              q_start, k_scale, v_scale, interpret=interpret)
+    h_ax = "model" if tp > 1 and n_kv % tp == 0 else None
     # normalize to [B, n_kv] OUTSIDE the map so scales partition by head
     ks = _norm_scales(k_scale, b, n_kv)
     vs = _norm_scales(v_scale, b, n_kv)
@@ -340,17 +342,17 @@ def paged_flash_mq_sharded(
     if "data" in mesh.axis_names and b % int(mesh.shape["data"]) == 0:
         b_ax = "data"
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(paged_flash_mq, interpret=interpret),
         mesh=mesh,
-        in_specs=(P(b_ax, None, "model", None),        # q (heads split)
-                  P(None, None, "model", None),        # k_pages (kv split)
-                  P(None, None, "model", None),        # v_pages
+        in_specs=(P(b_ax, None, h_ax, None),           # q (heads split)
+                  P(None, h_ax, None, None),           # k_pages (kv split)
+                  P(None, h_ax, None, None),           # v_pages
                   P(b_ax, None),                       # block tables
                   P(b_ax), P(b_ax),                    # lengths, q_start
-                  P(b_ax, "model"), P(b_ax, "model")),  # scales
-        out_specs=P(b_ax, None, "model", None),
-        check_rep=False,
+                  P(b_ax, h_ax), P(b_ax, h_ax)),       # scales
+        out_specs=P(b_ax, None, h_ax, None),
+        check_vma=False,
     )
     return fn(q, k_pages, v_pages, block_tables,
               lengths, q_start.astype(jnp.int32), ks, vs)
@@ -375,55 +377,11 @@ def paged_flash_decode_sharded(
     return out[:, 0]
 
 
-# Deployment hook: a TPU pod sets the serving mesh once and the
-# dispatchers below route every pallas-path call through shard_map.  The
-# engines deliberately DON'T set this (their CPU ref path shards via
-# GSPMD on the jit boundary instead) — a module global would leak TP
-# into same-process unsharded oracle engines.
-_TP_MESH: Optional[jax.sharding.Mesh] = None
-
-
-def set_tp_mesh(mesh: Optional[jax.sharding.Mesh]) -> None:
-    """Install (or clear, with None) the mesh the pallas-path
-    dispatchers shard over."""
-    global _TP_MESH
-    _TP_MESH = mesh
-
-
 def _resolve_impl(impl: Optional[str]) -> str:
     impl = impl or _DEFAULT_IMPL
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "ref"
     return impl
-
-
-def paged_attention(
-    q: jax.Array,
-    k_pages: jax.Array,
-    v_pages: jax.Array,
-    block_tables: jax.Array,
-    lengths: jax.Array,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-    *,
-    impl: Optional[str] = None,
-) -> jax.Array:
-    """Dispatching front door (decode, q [B, n_heads, hd]): Pallas
-    kernel on TPU, XLA ref elsewhere.
-
-    ``impl``: "auto" (default), "pallas", "pallas_interpret", or "ref".
-    """
-    impl = _resolve_impl(impl)
-    if impl == "ref":
-        return paged_attention_ref(q, k_pages, v_pages, block_tables,
-                                   lengths, k_scale, v_scale)
-    if _TP_MESH is not None:
-        return paged_flash_decode_sharded(
-            q, k_pages, v_pages, block_tables, lengths, k_scale, v_scale,
-            mesh=_TP_MESH, interpret=(impl == "pallas_interpret"))
-    return paged_flash_decode(q, k_pages, v_pages, block_tables, lengths,
-                              k_scale, v_scale,
-                              interpret=(impl == "pallas_interpret"))
 
 
 def paged_multiquery_attention(
@@ -439,17 +397,40 @@ def paged_multiquery_attention(
     impl: Optional[str] = None,
 ) -> jax.Array:
     """Dispatching front door for an S-query block (speculative verify,
-    paged multi-token prefill): same dispatch rules as
-    ``paged_attention``."""
+    paged multi-token prefill): Pallas kernel on TPU, XLA ref elsewhere.
+
+    ``impl``: "auto" (default), "pallas", "pallas_interpret", or "ref".
+    On the pallas paths a caller tracing under a mesh gets the
+    ``shard_map``'d kernel over that mesh."""
     impl = _resolve_impl(impl)
     if impl == "ref":
         return paged_attention_mq_ref(q, k_pages, v_pages, block_tables,
                                       lengths, q_start, k_scale, v_scale)
-    if _TP_MESH is not None:
+    interpret = impl == "pallas_interpret"
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
         return paged_flash_mq_sharded(
             q, k_pages, v_pages, block_tables, lengths, q_start,
-            k_scale, v_scale, mesh=_TP_MESH,
-            interpret=(impl == "pallas_interpret"))
+            k_scale, v_scale, mesh=mesh, interpret=interpret)
     return paged_flash_mq(q, k_pages, v_pages, block_tables, lengths,
-                          q_start, k_scale, v_scale,
-                          interpret=(impl == "pallas_interpret"))
+                          q_start, k_scale, v_scale, interpret=interpret)
+
+
+def paged_attention(
+    q: jax.Array,                  # [B, n_heads, hd]
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    block_tables: jax.Array,
+    lengths: jax.Array,
+    k_scale: Optional[jax.Array] = None,
+    v_scale: Optional[jax.Array] = None,
+    *,
+    impl: Optional[str] = None,
+) -> jax.Array:
+    """Dispatching front door for decode: the S=1 case of
+    ``paged_multiquery_attention``, its query at the last valid
+    position."""
+    out = paged_multiquery_attention(q[:, None], k_pages, v_pages,
+                                     block_tables, lengths, lengths - 1,
+                                     k_scale, v_scale, impl=impl)
+    return out[:, 0]

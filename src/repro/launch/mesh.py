@@ -8,33 +8,16 @@ sharding may span it (see repro.launch.shardings).
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
 __all__ = ["make_production_mesh", "make_host_mesh", "make_serve_mesh",
-           "batch_axes", "fsdp_axes", "mesh_context"]
+           "batch_axes", "fsdp_axes"]
 
 
 def _mk_mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
-    # newer jax wants explicit Auto axis types; 0.4.x has no AxisType
-    kw = {} if devices is None else {"devices": devices}
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes), **kw)
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes, **kw)
-
-
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where it exists (newer jax); a no-op
-    context on 0.4.x, where the plain ``with mesh:`` the callers pair
-    this with already provides the ambient mesh."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return contextlib.nullcontext()
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -29,9 +29,24 @@ from repro.configs import get_arch
 from repro.core.autotune import AutoTuner
 from repro.core.costmodel import (CLOUD_TITANXP_CLASS, Channel,
                                   EDGE_TX2_CLASS)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import init_lm, make_graph
 from repro.serve.engine import (CollaborativeServingEngine, SamplingParams,
                                 ServingEngine)
+
+
+def auto_cut(cfg, channel: Channel, prompt_len: int) -> int:
+    """Algorithm 1's partition point for ``cfg`` over ``channel``, as
+    the last edge block (a cut at the input keeps block 0 on the
+    edge)."""
+    graph = make_graph(cfg, batch=1, seq=prompt_len)
+    best, _ = AutoTuner(graph, EDGE_TX2_CLASS, CLOUD_TITANXP_CLASS).tune(
+        channel)
+    cut_layer = (int(best.point.split("/")[0][3:])
+                 if best.point.startswith("blk") else 0)
+    print(f"auto-tuned cut (Algorithm 1): {best.point} "
+          f"-> edge blocks 0..{cut_layer}")
+    return cut_layer
 
 
 def main(argv=None):
@@ -66,6 +81,7 @@ def main(argv=None):
                     help="base PRNG seed; request i samples with "
                          "seed+i so outputs replay bit-identically")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     assert spec.family == "lm", "serving launcher targets the LM family"
@@ -103,13 +119,7 @@ def main(argv=None):
 
     channel = Channel.from_kbps(args.bandwidth, rtt_ms=args.rtt)
     if args.cut == "auto":
-        graph = make_graph(cfg, batch=1, seq=args.prompt_len)
-        tuner = AutoTuner(graph, EDGE_TX2_CLASS, CLOUD_TITANXP_CLASS)
-        best, _ = tuner.tune(channel)
-        cut_layer = (int(best.point.split("/")[0][3:])
-                     if best.point.startswith("blk") else 0)
-        print(f"auto-tuned cut (Algorithm 1): {best.point} "
-              f"-> edge blocks 0..{cut_layer}")
+        cut_layer = auto_cut(cfg, channel, args.prompt_len)
     else:
         cut_layer = int(args.cut)
     if args.adaptive and cut_layer > cfg.n_layers - 2:

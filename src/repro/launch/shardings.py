@@ -184,7 +184,7 @@ def cache_sharding(mesh: Mesh, *, batch: int, seq: int, n_kv: int,
 def paged_pool_spec(mesh: Mesh, *, n_pages: int, n_kv: int,
                     head_dim: int) -> P:
     """PartitionSpec for a paged KV page pool
-    ``[L, n_pages, page_size, n_kv, head_dim]``: kv heads over ``model``
+    ``[L, n_pages, n_kv, page_size, head_dim]``: kv heads over ``model``
     — each TP shard stores, dequantizes, and attends only its own KV
     slice — and the page dim over the batch axes when divisible (pages
     are slot-owned, so this is "slots on the data axis" at page
@@ -195,7 +195,7 @@ def paged_pool_spec(mesh: Mesh, *, n_pages: int, n_kv: int,
     rematerialize the gathered pages (measured, not hypothetical)."""
     h_ax = _fit(n_kv, mesh, ("model",))
     p_ax = _fit(n_pages, mesh, batch_axes(mesh))
-    return P(None, p_ax, None, h_ax, None)
+    return P(None, p_ax, h_ax, None, None)
 
 
 def paged_scale_spec(mesh: Mesh, *, batch: int, n_kv: int) -> P:
@@ -216,7 +216,7 @@ def paged_pool_shardings(cache: Any, mesh: Mesh) -> Any:
     out = {}
     for k, v in cache.items():
         if k.endswith("_pages"):
-            _, n_pages, _, n_kv, hd = v.shape
+            _, n_pages, n_kv, _, hd = v.shape
             spec = paged_pool_spec(mesh, n_pages=n_pages, n_kv=n_kv,
                                    head_dim=hd)
         elif k.endswith("_scale"):

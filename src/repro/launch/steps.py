@@ -19,7 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import ShapeSpec, get_arch, input_specs
 from repro.launch import shardings as SH
-from repro.launch.mesh import batch_axes, mesh_context
+from repro.launch.mesh import batch_axes
 from repro.models import mmdit as MM
 from repro.models import resnet as RN
 from repro.models import transformer as TF
@@ -54,7 +54,7 @@ class Cell:
     def lower(self):
         # trace under the ambient mesh so bare-PartitionSpec sharding
         # constraints and shard_map calls inside model code resolve
-        with self.mesh, mesh_context(self.mesh):
+        with self.mesh, jax.set_mesh(self.mesh):
             return self.jit().lower(*self.args)
 
 

@@ -21,8 +21,7 @@ from repro.configs import get_arch
 from repro.data.pipeline import ImagePipeline, LatentPipeline, TokenPipeline
 from repro.distributed.checkpoint import (CheckpointManager, latest_step,
                                           restore_checkpoint)
-from repro.launch.mesh import (make_host_mesh, make_production_mesh,
-                              mesh_context)
+from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import build_cell
 
 
@@ -117,7 +116,7 @@ def main(argv=None):
     for step in range(start, args.steps):
         batch = _batch_for(cell, pipe, step, rng)
         t0 = time.perf_counter()
-        with mesh, mesh_context(mesh):
+        with mesh, jax.set_mesh(mesh):
             params, opt, metrics = compiled(params, opt, batch)
         dt = time.perf_counter() - t0
         print(f"step {step + 1:4d} loss={float(metrics['loss']):.4f} "

@@ -428,7 +428,7 @@ def _paged_cache_attention(cache: Dict[str, jax.Array], qh: jax.Array,
                                                paged_multiquery_attention)
 
     b, s = kh.shape[:2]
-    page_size = cache["k_pages"].shape[1]
+    page_size = cache["k_pages"].shape[2]
     quantized = "k_scale" in cache
 
     if quantized:
@@ -464,8 +464,10 @@ def _paged_cache_attention(cache: Dict[str, jax.Array], qh: jax.Array,
             (cache_index + jnp.arange(s))[None], (b, s))
     page = jnp.take_along_axis(block_tables, t // page_size, axis=1)
     off = t % page_size
-    k_pages = cache["k_pages"].at[page, off].set(k_w)
-    v_pages = cache["v_pages"].at[page, off].set(v_w)
+    # pages are [n_pages, n_kv, page_size, hd]: the [B, S] index pair
+    # (page, off) selects a [n_kv, hd] column of one page per token
+    k_pages = cache["k_pages"].at[page, :, off].set(k_w)
+    v_pages = cache["v_pages"].at[page, :, off].set(v_w)
 
     new_cache = {"k_pages": k_pages, "v_pages": v_pages}
     if quantized:
@@ -645,7 +647,7 @@ def moe_sharded(p: Params, x: jax.Array, *, top_k: int,
         ``model_axis``, leaving the output d_model-sharded (matches the
         residual-stream act_pspec), then re-gathered by the caller.
 
-    Requires the ambient mesh (trace under ``with mesh:``).
+    Requires the ambient mesh (trace under ``jax.set_mesh(mesh)``).
     """
     b, s, d = x.shape
     n_e = p["router"]["w"].shape[1]
@@ -670,28 +672,14 @@ def moe_sharded(p: Params, x: jax.Array, *, top_k: int,
     wi = qw(qctx, f"{name}/wi", p["wi"])
     wg = qw(qctx, f"{name}/wg", p["wg"])
     wo = qw(qctx, f"{name}/wo", p["wo"])
-    y, aux = _shard_map_compat(
+    y, aux = jax.shard_map(
         local_moe,
         in_specs=(P(), P(None, None, model_axis), P(None, None, model_axis),
                   P(None, model_axis, None), P(batch_spec, None, None)),
         out_specs=(P(batch_spec, None, model_axis), P()),
+        check_vma=False,
     )(p["router"], wi, wg, wo, x)
     return y, aux
-
-
-def _shard_map_compat(f, *, in_specs, out_specs):
-    """Unchecked shard_map over the ambient mesh: ``jax.shard_map`` with
-    ``check_vma`` on newer jax, ``jax.experimental.shard_map.shard_map``
-    with the ambient physical mesh made explicit (and ``check_rep``) on
-    0.4.x, where no top-level alias exists."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    from jax._src.mesh import thread_resources
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=thread_resources.env.physical_mesh,
-                     in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
 
 
 # -- vision helpers -----------------------------------------------------------

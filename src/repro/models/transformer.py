@@ -229,7 +229,7 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None,
       per-(layer, kv-head) symmetric ``k_scale``/``v_scale`` ``[L, n_kv]``
       (calibrated off-line in deployment; init'd to a generic RMS).
     * **``paged=True``**: ``{"k_pages", "v_pages"}`` of shape
-      ``[L, num_pages, page_size, n_kv, hd]`` — a shared pool of pages
+      ``[L, num_pages, n_kv, page_size, hd]`` — a shared pool of pages
       addressed through a per-slot block table (see
       ``serve.engine.PageAllocator``); HBM is claimed page-by-page on
       demand instead of ``max_len`` up front.  With ``quantized=True``
@@ -246,7 +246,7 @@ def init_cache(cfg: LMConfig, batch: int, max_len: int, dtype=None,
         n_pages = num_pages if num_pages is not None else (
             batch * ((max_len + page_size - 1) // page_size) + 1)
         pdtype = jnp.int8 if quantized else (dtype or cfg.dtype)
-        shape = (n_layers, n_pages, page_size, cfg.n_kv, cfg.hd)
+        shape = (n_layers, n_pages, cfg.n_kv, page_size, cfg.hd)
         c = {"k_pages": jnp.zeros(shape, pdtype),
              "v_pages": jnp.zeros(shape, pdtype)}
         if quantized:
@@ -336,7 +336,7 @@ def _cache_span(cache: Dict[str, jax.Array],
     """Longest position the cache layout can address (for RoPE tables)."""
     if "k" in cache:
         return cache["k"].shape[2]
-    return block_tables.shape[1] * cache["k_pages"].shape[2]
+    return block_tables.shape[1] * cache["k_pages"].shape[3]
 
 
 def prefill(params: Params, tokens: jax.Array, cfg: LMConfig, *,

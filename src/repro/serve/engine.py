@@ -109,9 +109,10 @@ class CollaborativeServingEngine(_SpecDraftMixin, _SeedPathMixin,
     ``mesh`` places the engine on a ``("data", "model")`` device mesh
     (``launch.mesh.make_serve_mesh``): the cloud suffix weights and
     paged KV pool shard tensor-parallel over ``model`` while everything
-    edge-side replicates, so cloud prefill/decode/verify run as
-    mesh-jitted computations (``serve.sharding``) and the auto policy
-    prices the mesh via a TP-scaled cloud device model."""
+    edge-side replicates, and every phase runs as a mesh-jitted
+    computation (``serve.sharding``; the paged kernels, edge-side too,
+    then run ``shard_map``'d, which a Mosaic call needs on a mesh); the
+    auto policy prices the mesh via a TP-scaled cloud device model."""
 
     def __init__(self, params: Params, cfg: TF.LMConfig, *, cut_layer: int,
                  channel: Optional[Channel] = None, max_len: int = 128,
@@ -226,15 +227,17 @@ class CollaborativeServingEngine(_SpecDraftMixin, _SeedPathMixin,
 
         self._edge = jax.jit(self._edge_impl)
         self._cloud = jax.jit(self._cloud_impl)
-        self._edge_prefill = _jit_phase(self._edge_prefill_impl, donate=(3,))
+        self._edge_prefill = _jit_phase(self._edge_prefill_impl, donate=(3,),
+                                        mesh=mesh)
         self._cloud_prefill = _jit_phase(self._cloud_prefill_impl,
                                          donate=(4,), mesh=mesh)
-        self._edge_decode = _jit_phase(self._edge_decode_impl, donate=(3,))
+        self._edge_decode = _jit_phase(self._edge_decode_impl, donate=(3,),
+                                       mesh=mesh)
         self._cloud_decode = _jit_phase(self._cloud_decode_impl, donate=(4,),
                                         mesh=mesh)
         if self._spec_max > 1:
             self._draft_prefill = _jit_phase(self._draft_prefill_impl,
-                                             donate=(3,))
+                                             donate=(3,), mesh=mesh)
             # per-k jitted draft/verify (k is the scan length / q-block
             # width, a trace constant); built on first use of each k
             self._spec_jits: Dict[int, Tuple[Any, Any]] = {}

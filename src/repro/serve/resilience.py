@@ -91,19 +91,16 @@ class ResilientCollaborativeEngine(CollaborativeServingEngine):
             self._spec_max = 2
             self._spec_jits = {}
             self._draft_prefill = _jit_phase(self._draft_prefill_impl,
-                                             donate=(3,))
+                                             donate=(3,), mesh=self.mesh)
             self._set_cut(self.cut, count=False)
         self._edge_only_step = _jit_phase(self._edge_only_step_impl,
-                                          donate=(5, 6))
+                                          donate=(5, 6), mesh=self.mesh)
         self._edge_only_admit = _jit_phase(self._edge_only_prefill_impl,
-                                           donate=(4,))
-        # resync replays run the cloud suffix — under the mesh when TP'd
+                                           donate=(4,), mesh=self.mesh)
         self._resync_replay = _jit_phase(self._resync_replay_impl,
-                                         donate=(2,),
-                                         mesh=getattr(self, "mesh", None))
+                                         donate=(2,), mesh=self.mesh)
         self._resync_prefill = _jit_phase(self._resync_prefill_impl,
-                                          donate=(2,),
-                                          mesh=getattr(self, "mesh", None))
+                                          donate=(2,), mesh=self.mesh)
         self.cloud_down = False
         self._down_since: Optional[float] = None
         self._rounds_down = 0
@@ -216,7 +213,7 @@ class ResilientCollaborativeEngine(CollaborativeServingEngine):
             temps, top_ps, seeds = self._samp_vecs()
             fn = self._samp_jit("edge_only_step",
                                 self._edge_only_step_sample_impl,
-                                donate=(5, 6))
+                                donate=(5, 6), mesh=self.mesh)
             return fn(self.edge_blocks, self.draft_blocks, self.embed,
                       self.tail, cur, self._edge_cache, self._draft_cache,
                       pos, bt, temps, top_ps, seeds, self._offsets())
@@ -274,7 +271,7 @@ class ResilientCollaborativeEngine(CollaborativeServingEngine):
         if (self._samp_t[slots] > 0).any():
             fn = self._samp_jit("edge_only_admit",
                                 self._edge_only_prefill_sample_impl,
-                                donate=(4,))
+                                donate=(4,), mesh=self.mesh)
             self._draft_cache, cur, pos = fn(
                 self.draft_blocks, self.tail, blob, qp, self._draft_cache,
                 slots_j, bt_rows, plens_j, cur, pos,

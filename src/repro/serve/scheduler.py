@@ -46,26 +46,26 @@ def _bucket_len(plen: int, max_len: int) -> int:
 def _jit_phase(fn, donate: Tuple[int, ...] = (), mesh=None):
     """``jax.jit`` with the KV-cache argument(s) donated, so the page-pool
     scatter of every prefill/decode/verify updates the cache *in place*
-    on TPU/GPU instead of doubling resident cache bytes per step.  The
-    engines always consume the returned cache and never touch the donated
-    buffer again, so donation is safe.  XLA:CPU ignores donation and
-    warns per call, so off-accelerator we jit plain.
+    on TPU/GPU (the engines never touch a donated buffer again).  XLA:CPU
+    ignores donation and warns per call, so off-accelerator we jit plain.
 
-    ``mesh`` makes the phase a mesh-jitted computation: the call runs
-    under the mesh context, and GSPMD propagates the committed input
-    shardings (the TP-placed suffix weights and KV pool — see
-    ``serve.sharding``) through the whole phase."""
-    if donate and jax.default_backend() in ("tpu", "gpu"):
-        jf = jax.jit(fn, donate_argnums=donate)
-    else:
-        jf = jax.jit(fn)
+    ``mesh``: the phase is traced, called and ``.lower``-ed under
+    ``jax.set_mesh(mesh)``, so GSPMD propagates the committed input
+    shardings (``serve.sharding``) and the paged kernels, which GSPMD
+    cannot partition, run ``shard_map``'d (``kernels.paged_attention``)."""
+    accel = jax.default_backend() in ("tpu", "gpu")
+    jf = jax.jit(fn, donate_argnums=donate if accel else ())
     if mesh is None:
         return jf
 
-    def mesh_call(*args, **kwargs):
-        with mesh:
-            return jf(*args, **kwargs)
+    def under_mesh(call):
+        def run(*args, **kwargs):
+            with jax.set_mesh(mesh):
+                return call(*args, **kwargs)
+        return run
 
+    mesh_call = under_mesh(jf)
+    mesh_call.lower = under_mesh(jf.lower)
     return mesh_call
 
 

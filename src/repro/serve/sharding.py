@@ -39,9 +39,9 @@ from jax.sharding import PartitionSpec as P
 from repro.launch.shardings import (cache_spec, paged_pool_shardings,
                                     spec_for_param, _path_str)
 
-__all__ = ["tp_size", "replicate_to_mesh", "shard_suffix_blocks",
-           "shard_tail", "shard_cloud_cache", "place_collab_engine",
-           "place_cloud_engine"]
+__all__ = ["tp_size", "replicate_to_mesh", "suffix_block_shardings",
+           "tail_shardings", "cloud_cache_shardings", "collab_shardings",
+           "place_collab_engine", "place_cloud_engine"]
 
 
 def tp_size(mesh: Optional[Mesh]) -> int:
@@ -56,43 +56,38 @@ def replicate_to_mesh(tree: Any, mesh: Mesh) -> Any:
     return jax.device_put(tree, NamedSharding(mesh, P()))
 
 
-def shard_suffix_blocks(blocks: Any, mesh: Mesh) -> Any:
-    """TP-shard a stacked ``[L, ...]`` suffix block tree with the
-    role-based param rules (paths resolved under a ``blocks/`` root so
-    the stacked-layer lead dim stays unsharded)."""
-    flat, tdef = jax.tree_util.tree_flatten_with_path(blocks)
-    placed = []
-    for path, leaf in flat:
-        spec = spec_for_param("blocks/" + _path_str(path),
-                              tuple(leaf.shape), mesh, zero1=True)
-        placed.append(jax.device_put(leaf, NamedSharding(mesh, spec)))
-    return jax.tree_util.tree_unflatten(tdef, placed)
+def _role_shardings(tree: Any, mesh: Mesh, root: str) -> Any:
+    """NamedSharding tree from the role-based param rules, each leaf's
+    path resolved under ``root``."""
+    flat, tdef = jax.tree_util.tree_flatten_with_path(tree)
+    return jax.tree_util.tree_unflatten(tdef, [
+        NamedSharding(mesh, spec_for_param(root + _path_str(path),
+                                           tuple(leaf.shape), mesh,
+                                           zero1=True))
+        for path, leaf in flat])
 
 
-def shard_tail(tail: Dict[str, Any], mesh: Mesh) -> Dict[str, Any]:
-    """Place the head: ``lm_head`` vocab-column-split when divisible
-    (rank-2 generic rule), norms replicated."""
-    out = {}
-    for name, sub in tail.items():
-        flat, tdef = jax.tree_util.tree_flatten_with_path(sub)
-        placed = []
-        for path, leaf in flat:
-            spec = spec_for_param(f"{name}/{_path_str(path)}",
-                                  tuple(leaf.shape), mesh, zero1=True)
-            placed.append(jax.device_put(leaf, NamedSharding(mesh, spec)))
-        out[name] = jax.tree_util.tree_unflatten(tdef, placed)
-    return out
+def suffix_block_shardings(blocks: Any, mesh: Mesh) -> Any:
+    """TP shardings of a stacked ``[L, ...]`` suffix block tree (paths
+    resolved under a ``blocks/`` root so the stacked-layer lead dim
+    stays unsharded)."""
+    return _role_shardings(blocks, mesh, "blocks/")
 
 
-def shard_cloud_cache(cache: Dict[str, jax.Array],
-                      mesh: Mesh) -> Dict[str, jax.Array]:
-    """Place a cloud KV cache: paged pools shard kv-heads over ``model``
-    and pages over ``data`` (divisibility-guarded); dense caches shard
-    via ``cache_spec`` on k/v, scales replicated."""
+def tail_shardings(tail: Dict[str, Any], mesh: Mesh) -> Dict[str, Any]:
+    """The head: ``lm_head`` vocab-column-split when divisible (rank-2
+    generic rule), norms replicated."""
+    return {name: _role_shardings(sub, mesh, f"{name}/")
+            for name, sub in tail.items()}
+
+
+def cloud_cache_shardings(cache: Dict[str, jax.Array],
+                          mesh: Mesh) -> Dict[str, NamedSharding]:
+    """A cloud KV cache: paged pools shard kv-heads over ``model`` and
+    pages over ``data`` (divisibility-guarded); dense caches shard via
+    ``cache_spec`` on k/v, scales replicated."""
     if "k_pages" in cache:
-        shardings = paged_pool_shardings(cache, mesh)
-        return {k: jax.device_put(v, shardings[k])
-                for k, v in cache.items()}
+        return paged_pool_shardings(cache, mesh)
     out = {}
     for k, v in cache.items():
         if k in ("k", "v"):
@@ -100,30 +95,38 @@ def shard_cloud_cache(cache: Dict[str, jax.Array],
             spec = cache_spec(mesh, batch=b, seq=s, n_kv=h, head_dim=d)
         else:
             spec = P()
-        out[k] = jax.device_put(v, NamedSharding(mesh, spec))
+        out[k] = NamedSharding(mesh, spec)
+    return out
+
+
+def collab_shardings(eng, mesh: Mesh) -> Dict[str, Any]:
+    """Sharding of every piece of a ``CollaborativeServingEngine``'s
+    device state, by attribute name: cloud half TP-sharded, edge half
+    replicated (a whole-tree ``NamedSharding``)."""
+    rep = NamedSharding(mesh, P())
+    out = {"embed": rep, "tail": tail_shardings(eng.tail, mesh),
+           "edge_blocks": rep,
+           "cloud_blocks": suffix_block_shardings(eng.cloud_blocks, mesh),
+           "_edge_cache": rep,
+           "_cloud_cache": cloud_cache_shardings(eng._cloud_cache, mesh)}
+    if eng.draft_blocks is not None:
+        out["draft_blocks"] = rep
+    if getattr(eng, "_draft_cache", None) is not None:
+        out["_draft_cache"] = rep
     return out
 
 
 def place_collab_engine(eng) -> None:
     """Place ALL of a ``CollaborativeServingEngine``'s device state onto
-    its mesh in one pass — cloud half TP-sharded, edge half replicated.
-    Called at construction and after every re-partition (``_set_cut``),
-    so a cut switch re-shards the new suffix slice.  Placing the edge
-    half too (replicated) is required: every phase jit must see one
-    consistent committed device set (see the module docstring)."""
-    mesh = eng.mesh
-    if mesh is None:
+    its mesh in one pass (``collab_shardings``).  Called at construction
+    and after every re-partition (``_set_cut``), so a cut switch
+    re-shards the new suffix slice.  Placing the edge half too
+    (replicated) is required: every phase jit must see one consistent
+    committed device set (see the module docstring)."""
+    if eng.mesh is None:
         return
-    eng.embed = replicate_to_mesh(eng.embed, mesh)
-    eng.tail = shard_tail(eng.tail, mesh)
-    eng.edge_blocks = replicate_to_mesh(eng.edge_blocks, mesh)
-    eng.cloud_blocks = shard_suffix_blocks(eng.cloud_blocks, mesh)
-    if eng.draft_blocks is not None:
-        eng.draft_blocks = replicate_to_mesh(eng.draft_blocks, mesh)
-    eng._edge_cache = replicate_to_mesh(eng._edge_cache, mesh)
-    eng._cloud_cache = shard_cloud_cache(eng._cloud_cache, mesh)
-    if getattr(eng, "_draft_cache", None) is not None:
-        eng._draft_cache = replicate_to_mesh(eng._draft_cache, mesh)
+    for name, sharding in collab_shardings(eng, eng.mesh).items():
+        setattr(eng, name, jax.device_put(getattr(eng, name), sharding))
 
 
 def place_cloud_engine(eng) -> None:
@@ -137,4 +140,5 @@ def place_cloud_engine(eng) -> None:
     from repro.launch.shardings import param_shardings
     eng.params = jax.device_put(
         eng.params, param_shardings(eng.params, mesh, zero1=True))
-    eng._cache = shard_cloud_cache(eng._cache, mesh)
+    eng._cache = jax.device_put(eng._cache,
+                                cloud_cache_shardings(eng._cache, mesh))

@@ -81,10 +81,11 @@ class _SpecDraftMixin:
 
     def _spec_fns(self, k: int):
         if k not in self._spec_jits:
+            mesh = getattr(self, "mesh", None)
             draft = _jit_phase(partial(self._spec_draft_impl, k),
-                               donate=(5, 6))
+                               donate=(5, 6), mesh=mesh)
             verify = _jit_phase(partial(self._verify_impl, k), donate=(6,),
-                                mesh=getattr(self, "mesh", None))
+                                mesh=mesh)
             self._spec_jits[k] = (draft, verify)
         return self._spec_jits[k]
 
@@ -96,11 +97,11 @@ class _SpecDraftMixin:
         if not hasattr(self, "_spec_sample_jits"):
             self._spec_sample_jits: Dict[int, Tuple[Any, Any]] = {}
         if k not in self._spec_sample_jits:
+            mesh = getattr(self, "mesh", None)
             draft = _jit_phase(partial(self._spec_draft_sample_impl, k),
-                               donate=(5, 6))
+                               donate=(5, 6), mesh=mesh)
             verify = _jit_phase(partial(self._verify_sample_impl, k),
-                                donate=(7,),
-                                mesh=getattr(self, "mesh", None))
+                                donate=(7,), mesh=mesh)
             self._spec_sample_jits[k] = (draft, verify)
         return self._spec_sample_jits[k]
 
@@ -242,7 +243,8 @@ class _SpecDraftMixin:
             return
         if not hasattr(self, "_draft_rebuild"):
             self._draft_rebuild = _jit_phase(self._draft_rebuild_impl,
-                                             donate=(4,))
+                                             donate=(4,),
+                                             mesh=getattr(self, "mesh", None))
         slots = sorted(live)
         rows = []
         for s in slots:

@@ -8,7 +8,7 @@ import pytest
 
 from repro.configs import (REGISTRY, get_arch, input_specs, list_archs,
                            list_cells)
-from repro.launch.mesh import make_host_mesh, mesh_context
+from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import build_cell
 
 jax.config.update("jax_platform_name", "cpu")
@@ -96,7 +96,7 @@ def test_smoke_train_step_runs_and_is_finite(arch, host_mesh):
     def init_like(tree):
         return jax.tree_util.tree_map(concretize, tree)
 
-    with cell.mesh, mesh_context(cell.mesh):
+    with cell.mesh, jax.set_mesh(cell.mesh):
         concrete = jax.tree_util.tree_map(concretize, cell.args,
                                           is_leaf=lambda x: isinstance(
                                               x, jax.ShapeDtypeStruct))

@@ -13,7 +13,7 @@ SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
-    from repro.launch.mesh import _mk_mesh, mesh_context
+    from repro.launch.mesh import _mk_mesh
 
     mesh = _mk_mesh((4, 2), ("data", "model"))
 
@@ -25,7 +25,7 @@ SCRIPT = textwrap.dedent("""
     x = jnp.asarray(np.random.RandomState(1).randn(8, 6, d), np.float32)
     y_ref, aux_ref = L.moe(p, x, top_k=K, capacity_factor=4.0)
 
-    with mesh, mesh_context(mesh):
+    with mesh, jax.set_mesh(mesh):
         y_sh, aux_sh = jax.jit(lambda p, x: L.moe_sharded(
             p, x, top_k=K, batch_spec="data", model_axis="model"))(p, x)
     # sharded path routes per data-shard (2 tokens fewer per capacity
@@ -48,7 +48,7 @@ SCRIPT = textwrap.dedent("""
             return jnp.asarray(np.abs(rng.randn(*x.shape)).astype(x.dtype)
                                * 0.02)
         return x
-    with mesh, mesh_context(mesh):
+    with mesh, jax.set_mesh(mesh):
         args = jax.tree_util.tree_map(
             conc, cell.args,
             is_leaf=lambda v: isinstance(v, jax.ShapeDtypeStruct))

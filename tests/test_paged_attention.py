@@ -44,14 +44,14 @@ def _rand_paged(seed, *, b=3, n_heads=8, n_kv=4, hd=16, page=8, n_pages=14,
     q = jnp.asarray(rng.randn(b, n_heads, hd).astype(np.float32))
     if int8:
         kp = jnp.asarray(
-            rng.randint(-127, 128, (n_pages, page, n_kv, hd)).astype(np.int8))
+            rng.randint(-127, 128, (n_pages, n_kv, page, hd)).astype(np.int8))
         vp = jnp.asarray(
-            rng.randint(-127, 128, (n_pages, page, n_kv, hd)).astype(np.int8))
+            rng.randint(-127, 128, (n_pages, n_kv, page, hd)).astype(np.int8))
         ks = jnp.asarray(rng.uniform(0.01, 0.05, (b, n_kv)).astype(np.float32))
         vs = jnp.asarray(rng.uniform(0.01, 0.05, (b, n_kv)).astype(np.float32))
     else:
-        kp = jnp.asarray(rng.randn(n_pages, page, n_kv, hd).astype(np.float32))
-        vp = jnp.asarray(rng.randn(n_pages, page, n_kv, hd).astype(np.float32))
+        kp = jnp.asarray(rng.randn(n_pages, n_kv, page, hd).astype(np.float32))
+        vp = jnp.asarray(rng.randn(n_pages, n_kv, page, hd).astype(np.float32))
         ks = vs = None
     # each row gets its own permutation of physical pages (never page 0)
     bt = jnp.asarray(np.stack([
@@ -98,9 +98,9 @@ def test_ref_matches_dense_sdpa():
                                             n_kv=2)
     ref = paged_attention_ref(q, kp, vp, bt, lens)
     b, n_heads, hd = q.shape
-    span = bt.shape[1] * kp.shape[1]
-    k = kp[bt].reshape(b, span, 2, hd)
-    v = vp[bt].reshape(b, span, 2, hd)
+    span = bt.shape[1] * kp.shape[2]
+    k = kp[bt].transpose(0, 1, 3, 2, 4).reshape(b, span, 2, hd)
+    v = vp[bt].transpose(0, 1, 3, 2, 4).reshape(b, span, 2, hd)
     k = jnp.repeat(k, 2, axis=2)
     v = jnp.repeat(v, 2, axis=2)
     dense = _sdpa(q[:, None], k, v, causal=True, q_offset=lens - 1)[:, 0]

@@ -82,8 +82,8 @@ SCRIPT = textwrap.dedent("""
     rng = np.random.RandomState(7)
     B, S, H, NKV, HD, PAGE, NP, PPS = 2, 3, 8, 4, 16, 8, 12, 4
     q = jnp.asarray(rng.randn(B, S, H, HD), jnp.float32)
-    kp = jnp.asarray(rng.randint(-127, 127, (NP, PAGE, NKV, HD)), jnp.int8)
-    vp = jnp.asarray(rng.randint(-127, 127, (NP, PAGE, NKV, HD)), jnp.int8)
+    kp = jnp.asarray(rng.randint(-127, 127, (NP, NKV, PAGE, HD)), jnp.int8)
+    vp = jnp.asarray(rng.randint(-127, 127, (NP, NKV, PAGE, HD)), jnp.int8)
     bt = jnp.asarray(rng.permutation(NP)[:B * PPS].reshape(B, PPS),
                      jnp.int32)
     lens = jnp.asarray([17, 25], jnp.int32)

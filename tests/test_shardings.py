@@ -92,11 +92,11 @@ if st is not None:
         n_pages, page = seq, 8
         pool = paged_pool_spec(mesh, n_pages=n_pages, n_kv=n_kv,
                                head_dim=head_dim)
-        _assert_divisible(pool, (3, n_pages, page, n_kv, head_dim), mesh,
+        _assert_divisible(pool, (3, n_pages, n_kv, page, head_dim), mesh,
                           "paged pool")
-        # the pool's guarded dims are exactly kv-heads (TP) and pages
-        # (data); the page payload [page_size, head_dim] is the DMA unit
-        assert pool[0] is None and pool[2] is None and pool[4] is None
+        # the pool's guarded dims are exactly pages (data) and kv-heads
+        # (TP); the page payload [page_size, head_dim] is the DMA unit
+        assert pool[0] is None and pool[3] is None and pool[4] is None
         scale = paged_scale_spec(mesh, batch=batch, n_kv=n_kv)
         _assert_divisible(scale, (3, batch, n_kv), mesh, "pool scales")
 else:
@@ -118,7 +118,7 @@ def test_pool_replicates_heads_when_tp_does_not_divide():
     # whole pool replicates
     assert spec == P(None, None, None, None, None)
     assert paged_pool_spec(mesh, n_pages=32, n_kv=8, head_dim=64) == \
-        P(None, "data", None, "model", None)
+        P(None, "data", "model", None, None)
 
 
 # ---------------------------------------------------------------------------
