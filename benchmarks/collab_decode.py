@@ -36,7 +36,7 @@ NEW = 16
 def _engine(params, channel):
     return CollaborativeServingEngine(params, CFG, cut_layer=CUT,
                                       channel=channel, max_len=PLEN + NEW,
-                                      max_batch=BATCH, timed=True)
+                                      max_batch=BATCH)
 
 
 def run(print_fn=print) -> dict:
@@ -84,8 +84,6 @@ def run(print_fn=print) -> dict:
             "us_per_token": t_inc / (NEW * BATCH) * 1e6,
             "bytes_per_token": inc_stats["bytes_per_decode_token"],
             "prefill_bytes": inc_stats["prefill_bytes"],
-            "prefill_s": inc_stats["prefill_s"],
-            "decode_s": inc_stats["decode_s"],
             "channel_latency_s": inc_stats["channel_latency_s"],
         },
         "recompute_baseline": {
@@ -105,8 +103,7 @@ def run(print_fn=print) -> dict:
     i, r = result["incremental"], result["recompute_baseline"]
     print_fn(f"incremental: {i['us_per_token']:9.1f} us/token  "
              f"{i['bytes_per_token']:7.1f} B/token  "
-             f"(prefill {i['prefill_s']:.3f}s / decode {i['decode_s']:.3f}s "
-             f"/ wire {i['channel_latency_s']:.3f}s)")
+             f"(wire {i['channel_latency_s']:.3f}s)")
     print_fn(f"recompute:   {r['us_per_token']:9.1f} us/token  "
              f"{r['bytes_per_token']:7.1f} B/token  "
              f"(wire {r['channel_latency_s']:.3f}s)")

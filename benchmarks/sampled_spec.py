@@ -55,7 +55,7 @@ CHANNEL = Channel.from_kbps(500, rtt_ms=100)
 def _engine(params, k, max_len):
     return CollaborativeServingEngine(params, CFG, cut_layer=CUT,
                                       channel=CHANNEL, max_len=max_len,
-                                      max_batch=BATCH, spec_k=k, timed=True)
+                                      max_batch=BATCH, spec_k=k)
 
 
 def _sampling(temp):

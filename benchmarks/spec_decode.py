@@ -53,7 +53,7 @@ CHANNEL = Channel.from_kbps(500, rtt_ms=100)
 def _engine(params, k, max_len):
     return CollaborativeServingEngine(params, CFG, cut_layer=CUT,
                                       channel=CHANNEL, max_len=max_len,
-                                      max_batch=BATCH, spec_k=k, timed=True)
+                                      max_batch=BATCH, spec_k=k)
 
 
 def _measure(eng, prompts, new_tokens):
@@ -74,7 +74,6 @@ def _measure(eng, prompts, new_tokens):
         "uplink_bytes_per_accepted_token": s.bytes_per_decode_token(),
         "wire_bytes_per_accepted_token": s.wire_bytes_per_accepted_token(),
         "channel_latency_s": s.channel_latency_s,
-        "decode_s": s.decode_s,
     }
 
 

@@ -246,7 +246,7 @@ def main(overload: bool = False, mesh_n: int = 1, fleet_n: int = 0,
 
     collab = CollaborativeServingEngine(params, CFG, cut_layer=cut_layer,
                                         channel=channel, max_len=64,
-                                        max_batch=4, timed=True, mesh=mesh)
+                                        max_batch=4, mesh=mesh)
     t0 = time.perf_counter()
     got = collab.generate(prompts, max_new_tokens=8)
     t_collab = time.perf_counter() - t0
@@ -254,8 +254,7 @@ def main(overload: bool = False, mesh_n: int = 1, fleet_n: int = 0,
                      for a, b in zip(r, g)])
     s = collab.stats
     print(f"collaborative (cut after block {cut_layer}): {t_collab:.2f}s "
-          f"(prefill {s.prefill_s:.2f}s / decode {s.decode_s:.2f}s / "
-          f"simulated wire {s.channel_latency_s:.2f}s)")
+          f"(simulated wire {s.channel_latency_s:.2f}s)")
     print(f"  wire: {s.prefill_bytes / 1e3:.1f}KB one-time prefill + "
           f"{s.bytes_per_decode_token():.0f} B per generated token "
           f"(constant — the [B,1,D] Eq.(1) delta)")

@@ -16,6 +16,8 @@ cloud-edge collaborative deployment, as a package of focused layers.
     fleet       ``FleetServingEngine`` — N tenant edges on one shared
                 cloud engine: cross-tenant batched verify over one
                 weight bank / page pool, weighted-fair sharing
+    trace       host spans of the serving loop on the device trace's
+                clock (off unless ``trace.enable(True)``)
 
 ``repro.serve.engine`` re-exports the whole public surface, so both
 ``from repro.serve import X`` and the historical

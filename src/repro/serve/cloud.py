@@ -35,10 +35,9 @@ class ServingEngine(_SlotEngine):
                  max_batch: int = 4, max_len: int = 128,
                  paged: bool = False, page_size: int = 16,
                  int8_kv: bool = False, num_pages: Optional[int] = None,
-                 cache_dtype=None, timed: bool = False,
+                 cache_dtype=None,
                  mesh: Optional[jax.sharding.Mesh] = None):
-        super().__init__(cfg, max_batch=max_batch, max_len=max_len,
-                         timed=timed)
+        super().__init__(cfg, max_batch=max_batch, max_len=max_len)
         self.mesh = mesh
         self.params = params
         self.paged = paged

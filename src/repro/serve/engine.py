@@ -46,12 +46,13 @@ import numpy as np
 from repro.core.costmodel import CLOUD_TITANXP_CLASS, Channel
 from repro.models import layers as ML
 from repro.models import transformer as TF
+from repro.serve import trace
 # re-export shims: the pre-split monolith lived at repro.serve.engine and
 # external code imports these names from here
 from repro.serve.cloud import ServingEngine
 from repro.serve.kvcache import (PageAllocator, PoolExhausted, _cdiv,
                                  _PagedPool, _paged_prefill_merge,
-                                 _paged_prefill_view)
+                                 _paged_prefill_view, page_visits)
 from repro.serve.policy import (AdaptivePolicy, DeadlineAdmission, Decision,
                                 _CutBank)
 from repro.serve.scheduler import (Request, _bucket_len, _jit_phase,
@@ -126,12 +127,10 @@ class CollaborativeServingEngine(_SpecDraftMixin, _SeedPathMixin,
                  demand_paged: bool = False,
                  pressure: Optional[PressureSchedule] = None,
                  admission: Union[DeadlineAdmission, str, None] = None,
-                 mesh: Optional[jax.sharding.Mesh] = None,
-                 timed: bool = False):
+                 mesh: Optional[jax.sharding.Mesh] = None):
         assert 0 <= cut_layer < cfg.n_layers, \
             f"cut_layer {cut_layer} outside [0, {cfg.n_layers})"
-        super().__init__(cfg, max_batch=max_batch, max_len=max_len,
-                         timed=timed)
+        super().__init__(cfg, max_batch=max_batch, max_len=max_len)
         self.mesh = mesh
         self.cut = cut_layer
         self.transport = Transport(channel)
@@ -476,51 +475,66 @@ class CollaborativeServingEngine(_SpecDraftMixin, _SeedPathMixin,
             return cur, pos, cur[:, None], None
         k, n_active = self.spec_k, len(slots)
         bt = self._pool.table_dev() if self._pool is not None else None
-        if sampled:
-            temps, top_ps, seeds = self._samp_vecs()
-            offs = self._offsets()
-            draft_fn, verify_fn = self._spec_sample_fns(k)
-            (blobs, scales, zps, drafts, qs, self._edge_cache,
-             self._draft_cache) = draft_fn(
-                self.edge_blocks, self.draft_blocks, self.embed, self.tail,
-                cur, self._edge_cache, self._draft_cache, pos, bt, temps,
-                top_ps, seeds, offs)
-        else:
-            draft_fn, verify_fn = self._spec_fns(k)
-            (blobs, scales, zps, drafts, self._edge_cache,
-             self._draft_cache) = draft_fn(
-                self.edge_blocks, self.draft_blocks, self.embed, self.tail,
-                cur, self._edge_cache, self._draft_cache, pos, bt)
-        # one uplink message: k per-row-framed [1, D] deltas + the k-1
-        # graded drafts, amortizing the header (and the RTT) over a round;
-        # a sampled row additionally ships the k-1 graded positions' f32
-        # draft distributions the rejection test needs
-        # (costmodel.speculative_round_time prices this as draft_q_bytes)
-        n_samp = int((self._samp_t[slots] > 0).sum())
-        self.transport.charge(
-            self.stats,
-            n_active * (k * (self.cfg.d_model * blobs.dtype.itemsize
-                             + _QP_BYTES)
-                        + (k - 1) * _TOK_BYTES) + _MSG_BYTES
-            + n_samp * (k - 1) * self.cfg.vocab * 4,
-            phase="decode")
-        if sampled:
-            toks, n_commit, cur, self._cloud_cache, pos = verify_fn(
-                self.cloud_blocks, self.tail, blobs, scales, zps, drafts,
-                qs, self._cloud_cache, pos, bt, temps, top_ps, seeds, offs)
-        else:
-            toks, n_commit, cur, self._cloud_cache, pos = verify_fn(
-                self.cloud_blocks, self.tail, blobs, scales, zps, drafts,
-                self._cloud_cache, pos, bt)
+        with trace.span("engine.dispatch"):
+            if sampled:
+                temps, top_ps, seeds = self._samp_vecs()
+                offs = self._offsets()
+                draft_fn, verify_fn = self._spec_sample_fns(k)
+                (blobs, scales, zps, drafts, qs, self._edge_cache,
+                 self._draft_cache) = draft_fn(
+                    self.edge_blocks, self.draft_blocks, self.embed,
+                    self.tail, cur, self._edge_cache, self._draft_cache, pos,
+                    bt, temps, top_ps, seeds, offs)
+            else:
+                draft_fn, verify_fn = self._spec_fns(k)
+                (blobs, scales, zps, drafts, self._edge_cache,
+                 self._draft_cache) = draft_fn(
+                    self.edge_blocks, self.draft_blocks, self.embed,
+                    self.tail, cur, self._edge_cache, self._draft_cache, pos,
+                    bt)
+            # one uplink message: k per-row-framed [1, D] deltas + the k-1
+            # graded drafts, amortizing the header (and the RTT) over a
+            # round; a sampled row also ships the k-1 graded positions'
+            # f32 draft distributions the rejection test needs (priced as
+            # draft_q_bytes by costmodel.speculative_round_time)
+            n_samp = int((self._samp_t[slots] > 0).sum())
+            self.transport.charge(
+                self.stats,
+                n_active * (k * (self.cfg.d_model * blobs.dtype.itemsize
+                                 + _QP_BYTES)
+                            + (k - 1) * _TOK_BYTES) + _MSG_BYTES
+                + n_samp * (k - 1) * self.cfg.vocab * 4,
+                phase="decode")
+            if sampled:
+                toks, n_commit, cur, self._cloud_cache, pos = verify_fn(
+                    self.cloud_blocks, self.tail, blobs, scales, zps, drafts,
+                    qs, self._cloud_cache, pos, bt, temps, top_ps, seeds,
+                    offs)
+            else:
+                toks, n_commit, cur, self._cloud_cache, pos = verify_fn(
+                    self.cloud_blocks, self.tail, blobs, scales, zps, drafts,
+                    self._cloud_cache, pos, bt)
         # the edge needs the accept counts to schedule the next round, so
         # this sync is part of the protocol, not a host-loop artifact
-        counts = np.asarray(n_commit)
+        with trace.span("engine.sync"):
+            counts = np.asarray(n_commit)
         self.transport.account_downlink(self.stats, n_active, k=k)
         self.stats.spec_rounds += 1
         hits = int(np.minimum(counts[slots] - 1, k - 1).sum())
         self.stats.drafted_tokens += (k - 1) * n_active
         self.stats.draft_hits += hits
         self.telemetry.observe_round((k - 1) * n_active, hits)
+        if bt is not None:
+            # per round: k draft passes through the edge and draft layers
+            # (paged with the edge), one verify through the cloud layers
+            live_pos = [len(r.prompt) + c - 1
+                        for r, c in self._sched_active.values()]
+            visited, live = page_visits(
+                live_pos, k, self.max_batch, bt.shape[1], self.page_size,
+                self.max_len, (self.n_edge + self.n_cloud) * self.edge_paged,
+                self.n_cloud * self.cloud_paged)
+            self.stats.kv_pages_visited += visited
+            self.stats.kv_pages_live += live
         return cur, pos, toks, counts
 
     def _retire(self, slot):

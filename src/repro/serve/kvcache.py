@@ -291,6 +291,25 @@ class _PagedPool:
         return self._masked[key]
 
 
+def page_visits(pos: Sequence[int], k: int, rows: int, width: int,
+                page_size: int, max_len: int, draft_calls: int,
+                verify_calls: int) -> Tuple[int, int]:
+    """(Row, page) grid steps of one speculative round's paged-attention
+    calls, and how many of them hold a live key.  The kernel's grid runs
+    all ``rows`` of the block table over its ``width`` pages
+    (``kernels.paged_attention``); a live row holds keys only in the
+    pages below its KV length.  ``pos``: each live row's committed
+    length less one.  Draft pass i < k makes ``draft_calls`` calls that
+    read ``pos + i + 1`` keys, the verify ``verify_calls`` that read
+    ``pos + k``."""
+    keys = np.minimum(np.asarray(pos)[:, None] + np.arange(1, k + 1),
+                      max_len)
+    pages = -(-keys // page_size)
+    visited = (k * draft_calls + verify_calls) * rows * width
+    live = draft_calls * pages.sum() + verify_calls * pages[:, -1].sum()
+    return visited, int(live)
+
+
 def _paged_prefill_view(cache: Dict[str, jax.Array], n_layers: int, n: int,
                         n_kv: int) -> Dict[str, jax.Array]:
     """Group-local view of a paged cache for one prefill call: the

@@ -22,7 +22,6 @@ request-admission boundary, and the queue resumes on the new partition.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -31,6 +30,7 @@ import numpy as np
 
 from repro.models import layers as ML
 from repro.models import transformer as TF
+from repro.serve import trace
 from repro.serve.kvcache import PoolExhausted
 from repro.serve.transport import ServeStats
 
@@ -134,12 +134,12 @@ class _SlotEngine:
     retraces of the jit'd phase functions; tests pin it.
     """
 
-    def __init__(self, cfg: TF.LMConfig, *, max_batch: int, max_len: int,
-                 timed: bool = False):
+    _pool = None      # paged engines: their ``kvcache._PagedPool``
+
+    def __init__(self, cfg: TF.LMConfig, *, max_batch: int, max_len: int):
         self.cfg = dataclasses.replace(cfg, remat=False)
         self.max_batch = max_batch
         self.max_len = max_len
-        self.timed = timed
         self.stats = ServeStats()
         self.trace_counts = {"prefill": 0, "decode": 0, "spec_draft": 0,
                              "verify": 0, "edge_only": 0, "resync": 0,
@@ -272,15 +272,6 @@ class _SlotEngine:
         return ML.rope_table(self.max_len, self.cfg.hd,
                              base=self.cfg.rope_base, dtype=self.cfg.dtype)
 
-    def _timed(self, phase: str, fn):
-        if not self.timed:
-            return fn()
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn())
-        setattr(self.stats, phase,
-                getattr(self.stats, phase) + time.perf_counter() - t0)
-        return out
-
     @staticmethod
     def _eff_prompt(r: Request) -> np.ndarray:
         """The token row a (re-)admission prefills: the prompt — extended
@@ -306,12 +297,10 @@ class _SlotEngine:
         ``None`` (default) is greedy."""
         samps = (list(sampling) if isinstance(sampling, (list, tuple))
                  else [sampling] * len(prompts))
-        reqs = [Request(uid=i, prompt=np.asarray(p),
-                        max_new_tokens=max_new_tokens, sampling=s)
-                for i, (p, s) in enumerate(zip(prompts, samps))]
-        if reqs:
-            self._run(reqs)
-        return [r.out_tokens for r in reqs]
+        return self.generate_requests(
+            [Request(uid=i, prompt=np.asarray(p),
+                     max_new_tokens=max_new_tokens, sampling=s)
+             for i, (p, s) in enumerate(zip(prompts, samps))])
 
     def generate_requests(self, reqs: List[Request]) -> List[List[int]]:
         """Run caller-built ``Request``s — priorities, deadlines,
@@ -320,7 +309,8 @@ class _SlotEngine:
         ``r.shed`` set; completion metadata lands on ``admit_s`` /
         ``finish_s`` / ``preemptions``."""
         if reqs:
-            self._run(reqs)
+            with trace.span("serve.call", requests=len(reqs)):
+                self._run(reqs)
         return [r.out_tokens for r in reqs]
 
     def _run(self, reqs: List[Request]) -> None:
@@ -362,9 +352,10 @@ class _SlotEngine:
             free.append(slot)
             queue.append(r)
 
-        while queue or active:
-            self._tick_resources()
-            hold = self._policy_tick(len(active))
+        for _ in trace.turns(lambda: bool(queue or active)):
+            with trace.span("sched.policy"):
+                self._tick_resources()
+                hold = self._policy_tick(len(active))
             assert not (hold and not active), \
                 "_policy_tick must not pause admission on a drained engine"
             now = self._now()
@@ -434,48 +425,50 @@ class _SlotEngine:
                     slots.append(free.pop(0))
                 if not group:
                     break
-                toks = np.zeros((len(group), bucket), np.int32)
-                for i, row in enumerate(rows):
-                    toks[i, :len(row)] = row
-                plens = np.asarray([len(row) for row in rows], np.int32)
-                max_news = np.asarray([m for _, m in shapes], np.int32)
-                slots_a = np.asarray(slots, np.int32)
-                toks_j = jnp.asarray(toks)
-                cur, pos = self._timed(
-                    "prefill_s",
-                    lambda: self._admit(toks_j, plens, max_news, slots_a,
-                                        cur, pos,
-                                        samplings=[r.sampling
-                                                   for r in group]))
-                self.stats.prefill_calls += 1
-                self.stats.prefill_tokens += int(plens.sum())
-                resumes = [(s, r) for r, s in zip(group, slots)
-                           if r._parked is not None]
-                if resumes:
-                    # the replay prefill re-derives the last committed
-                    # token; pin the stream to the parked value so resume
-                    # can never diverge (INT8 recalibration over the
-                    # longer prefix may legitimately flip the argmax —
-                    # lossless mode is bitwise identical either way,
-                    # which the preemption property tests pin)
-                    rs = jnp.asarray([s for s, _ in resumes], jnp.int32)
-                    lasts = jnp.asarray([int(r._parked[-1])
-                                         for _, r in resumes], jnp.int32)
-                    cur = cur.at[rs].set(lasts)
-                # a fresh request's first committed token is the prefill
-                # argmax; a resumed request's tokens are already logged
-                # in its pre-preemption rounds
-                fresh = [(r, s, 1) for r, s in zip(group, slots)
-                         if r._parked is None]
-                if fresh:
-                    rounds.append((cur[:, None], fresh))
-                for r, s in zip(group, slots):
-                    active[s] = (r, 1 if r._parked is None
-                                 else len(r._parked))
-                    if r.admit_s is None:
-                        r.admit_s = now
-                    self.stats.queue_wait_s += max(0.0, now - r._enq_s)
-                    r._parked = None
+                with trace.span("sched.admit", bucket=bucket,
+                                group=len(group)) as sp:
+                    if sp is not None:
+                        sp.set_metadata(uids="_".join(str(r.uid)
+                                                      for r in group))
+                    toks = np.zeros((len(group), bucket), np.int32)
+                    for i, row in enumerate(rows):
+                        toks[i, :len(row)] = row
+                    plens = np.asarray([len(row) for row in rows], np.int32)
+                    max_news = np.asarray([m for _, m in shapes], np.int32)
+                    slots_a = np.asarray(slots, np.int32)
+                    toks_j = jnp.asarray(toks)
+                    cur, pos = self._admit(
+                        toks_j, plens, max_news, slots_a, cur, pos,
+                        samplings=[r.sampling for r in group])
+                    self.stats.prefill_calls += 1
+                    self.stats.prefill_tokens += int(plens.sum())
+                    resumes = [(s, r) for r, s in zip(group, slots)
+                               if r._parked is not None]
+                    if resumes:
+                        # the replay prefill re-derives the last committed
+                        # token; pin the stream to the parked value so resume
+                        # can never diverge (INT8 recalibration over the
+                        # longer prefix may legitimately flip the argmax —
+                        # lossless mode is bitwise identical either way,
+                        # which the preemption property tests pin)
+                        rs = jnp.asarray([s for s, _ in resumes], jnp.int32)
+                        lasts = jnp.asarray([int(r._parked[-1])
+                                             for _, r in resumes], jnp.int32)
+                        cur = cur.at[rs].set(lasts)
+                    # a fresh request's first committed token is the prefill
+                    # argmax; a resumed request's tokens are already logged
+                    # in its pre-preemption rounds
+                    fresh = [(r, s, 1) for r, s in zip(group, slots)
+                             if r._parked is None]
+                    if fresh:
+                        rounds.append((cur[:, None], fresh))
+                    for r, s in zip(group, slots):
+                        active[s] = (r, 1 if r._parked is None
+                                     else len(r._parked))
+                        if r.admit_s is None:
+                            r.admit_s = now
+                        self.stats.queue_wait_s += max(0.0, now - r._enq_s)
+                        r._parked = None
             if stalled and not active:
                 # a drained engine that still can't admit: either a
                 # transient squeeze (wait it out on the simulated clock
@@ -506,52 +499,62 @@ class _SlotEngine:
             # remaining budget — until the growth fits (possibly
             # preempting the grower itself, which also resolves it)
             if active:
-                k = self._round_width()
-                for s in sorted(active,
-                                key=lambda t: (-active[t][0].priority, t)):
-                    if s not in active:
-                        continue  # already someone else's victim
-                    r, c = active[s]
-                    horizon = min(len(r.prompt) + c - 1 + k, self.max_len)
-                    while s in active:
-                        try:
-                            self._ensure_slot(s, horizon)
-                            break
-                        except PoolExhausted:
-                            victims = sorted(
-                                active,
-                                key=lambda t: (
-                                    active[t][0].priority,
-                                    -(active[t][0].max_new_tokens
-                                      - active[t][1]),
-                                    t))
-                            preempt(victims[0])
+                with trace.span("sched.page"):
+                    k = self._round_width()
+                    for s in sorted(active,
+                                    key=lambda t: (-active[t][0].priority, t)):
+                        if s not in active:
+                            continue  # already someone else's victim
+                        r, c = active[s]
+                        horizon = min(len(r.prompt) + c - 1 + k, self.max_len)
+                        while s in active:
+                            try:
+                                self._ensure_slot(s, horizon)
+                                break
+                            except PoolExhausted:
+                                victims = sorted(
+                                    active,
+                                    key=lambda t: (
+                                        active[t][0].priority,
+                                        -(active[t][0].max_new_tokens
+                                          - active[t][1]),
+                                        t))
+                                preempt(victims[0])
             if active:
                 act_slots = np.asarray(sorted(active), np.int32)
-                cur, pos, toks_r, counts = self._timed(
-                    "decode_s",
-                    lambda: self._round(cur, pos, act_slots))
-                takes = []
-                for s in act_slots:
-                    r, c = active[int(s)]
-                    n = 1 if counts is None else int(counts[s])
-                    n = min(n, r.max_new_tokens - c)  # trim budget overshoot
-                    active[int(s)] = (r, c + n)
-                    takes.append((r, int(s), n))
-                rounds.append((toks_r, takes))
-                self.stats.decode_steps += 1
-                committed = sum(n for _, _, n in takes)
-                self.stats.decode_tokens += committed
-                self._after_round(len(takes), committed)
+                with trace.span("sched.round", round=self.stats.decode_steps,
+                                slots=len(act_slots),
+                                k=self._round_width()) as sp:
+                    if sp is not None and self._pool is not None:
+                        sp.set_metadata(width=self._pool.table_dev().shape[1])
+                    cur, pos, toks_r, counts = self._round(cur, pos,
+                                                           act_slots)
+                with trace.span("sched.commit") as sp:
+                    takes = []
+                    for s in act_slots:
+                        r, c = active[int(s)]
+                        n = 1 if counts is None else int(counts[s])
+                        n = min(n, r.max_new_tokens - c)  # trim overshoot
+                        active[int(s)] = (r, c + n)
+                        takes.append((r, int(s), n))
+                    rounds.append((toks_r, takes))
+                    self.stats.decode_steps += 1
+                    committed = sum(n for _, _, n in takes)
+                    self.stats.decode_tokens += committed
+                    self._after_round(len(takes), committed)
+                    if sp is not None:
+                        sp.set_metadata(uids="_".join(
+                            f"{r.uid}:{n}" for r, _, n in takes if n))
         self._sched_active = None
         self._sched_committed = None
         # single device→host transfer for the whole run
         if not rounds:
             return  # everything shed before a single token committed
-        all_toks = np.asarray(
-            jnp.concatenate([t for t, _ in rounds], axis=1))
-        col = 0
-        for toks_r, takes in rounds:
-            for r, s, n in takes:
-                r.out_tokens.extend(int(t) for t in all_toks[s, col:col + n])
-            col += toks_r.shape[1]
+        with trace.span("sched.finalize", blocks=len(rounds)):
+            all_toks = np.asarray(
+                jnp.concatenate([t for t, _ in rounds], axis=1))
+            col = 0
+            for toks_r, takes in rounds:
+                for r, s, n in takes:
+                    r.out_tokens.extend(all_toks[s, col:col + n].tolist())
+                col += toks_r.shape[1]

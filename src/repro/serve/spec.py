@@ -68,6 +68,15 @@ from repro.serve.kvcache import _paged_prefill_merge, _paged_prefill_view
 from repro.serve.scheduler import _bucket_len, _jit_phase
 
 
+def _at_k(impl, k: int):
+    """``impl`` with the draft length bound to ``k``, under ``impl``'s
+    name, so its compiled program is ``jit_<name>`` in a device trace
+    (a bare ``partial`` compiles as ``jit__unknown``)."""
+    fn = partial(impl, k)
+    fn.__name__ = fn.__qualname__ = impl.__name__
+    return fn
+
+
 class _SpecDraftMixin:
     """Draft/verify phase implementations, mixed into
     ``CollaborativeServingEngine`` (which provides cfg, caches, the
@@ -82,9 +91,9 @@ class _SpecDraftMixin:
     def _spec_fns(self, k: int):
         if k not in self._spec_jits:
             mesh = getattr(self, "mesh", None)
-            draft = _jit_phase(partial(self._spec_draft_impl, k),
+            draft = _jit_phase(_at_k(self._spec_draft_impl, k),
                                donate=(5, 6), mesh=mesh)
-            verify = _jit_phase(partial(self._verify_impl, k), donate=(6,),
+            verify = _jit_phase(_at_k(self._verify_impl, k), donate=(6,),
                                 mesh=mesh)
             self._spec_jits[k] = (draft, verify)
         return self._spec_jits[k]
@@ -98,9 +107,9 @@ class _SpecDraftMixin:
             self._spec_sample_jits: Dict[int, Tuple[Any, Any]] = {}
         if k not in self._spec_sample_jits:
             mesh = getattr(self, "mesh", None)
-            draft = _jit_phase(partial(self._spec_draft_sample_impl, k),
+            draft = _jit_phase(_at_k(self._spec_draft_sample_impl, k),
                                donate=(5, 6), mesh=mesh)
-            verify = _jit_phase(partial(self._verify_sample_impl, k),
+            verify = _jit_phase(_at_k(self._verify_sample_impl, k),
                                 donate=(7,), mesh=mesh)
             self._spec_sample_jits[k] = (draft, verify)
         return self._spec_sample_jits[k]

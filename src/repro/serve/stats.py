@@ -22,10 +22,11 @@ class ServeStats:
     ``spec_k_switches``/``cut_switches`` count online retune events
     applied by a ``serve.policy`` controller.
 
-    ``prefill_s``/``decode_s`` are wall-clock phase totals, populated
-    when the engine runs with ``timed=True`` (timing blocks on device
-    results, so it is off by default to keep the decode loop fully
-    async).
+    ``kv_pages_visited``/``kv_pages_live`` count the paged-attention
+    kernel's (row, page) grid steps in the collaborative engine's
+    speculative rounds, and those of them below the row's KV length:
+    their ratio is the share of page visits that read a live key.  Phase
+    timings are host spans on the profiler's clock (``serve.trace``).
 
     The fault counters are populated by ``ReliableTransport`` and the
     resilient engine (``serve.resilience``): ``retries`` counts
@@ -48,14 +49,15 @@ class ServeStats:
     decode_bytes_log: List[int] = dataclasses.field(default_factory=list)
     downlink_bytes: int = 0
     decode_downlink_bytes: int = 0
-    prefill_s: float = 0.0
-    decode_s: float = 0.0
     prefill_tokens: int = 0
     decode_tokens: int = 0
     # speculative draft/verify rounds
     spec_rounds: int = 0
     drafted_tokens: int = 0
     draft_hits: int = 0
+    # paged-attention grid steps of those rounds (CollaborativeServingEngine)
+    kv_pages_visited: int = 0
+    kv_pages_live: int = 0
     # online re-tuning events (serve.policy)
     spec_k_switches: int = 0
     cut_switches: int = 0
@@ -159,13 +161,13 @@ class ServeStats:
             "spec_rounds": self.spec_rounds,
             "drafted_tokens": self.drafted_tokens,
             "acceptance_rate": self.acceptance_rate(),
+            "kv_pages_visited": self.kv_pages_visited,
+            "kv_pages_live": self.kv_pages_live,
             "spec_k_switches": self.spec_k_switches,
             "cut_switches": self.cut_switches,
             "draft_rebuilds": self.draft_rebuilds,
             "policy_holds": self.policy_holds,
             "channel_latency_s": self.channel_latency_s,
-            "prefill_s": self.prefill_s,
-            "decode_s": self.decode_s,
             "retries": self.retries,
             "timeouts": self.timeouts,
             "corrupt_msgs": self.corrupt_msgs,

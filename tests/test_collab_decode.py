@@ -9,6 +9,7 @@ from repro.core.costmodel import Channel
 from repro.models import layers as ML
 from repro.models import transformer as TF
 from repro.models.transformer import LMConfig, forward, init_lm
+from repro.serve import trace
 from repro.serve.engine import CollaborativeServingEngine, ServingEngine
 
 jax.config.update("jax_platform_name", "cpu")
@@ -164,11 +165,22 @@ def test_collab_continuous_batching_frees_slots(params):
         + _MSG_BYTES
 
 
-def test_timed_mode_populates_phase_latency(params):
+def test_timed_mode_populates_phase_latency(params, tmp_path):
+    """Phases are timed by host spans on the profiler's clock
+    (``serve.trace``), which block nothing: a traced run records every
+    admission and every round with a positive duration."""
     eng = CollaborativeServingEngine(params, CFG, cut_layer=1, max_batch=2,
-                                     max_len=32, timed=True)
-    eng.generate(_prompts(2), max_new_tokens=3)
-    assert eng.stats.prefill_s > 0.0
-    assert eng.stats.decode_s > 0.0
+                                     max_len=32)
+    trace.enable(True)
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            eng.generate(_prompts(2), max_new_tokens=3)
+    finally:
+        trace.enable(False)
+    spans = trace.read(str(tmp_path))
+    for name, n in (("sched.admit", eng.stats.prefill_calls),
+                    ("sched.round", eng.stats.decode_steps)):
+        took = [b - a for s, a, b, _ in spans if s == name]
+        assert len(took) == n and min(took) > 0
     # 2 requests x (3 tokens = 1 prefill + 2 decode steps)
     assert eng.stats.prefill_tokens == 12 and eng.stats.decode_tokens == 4

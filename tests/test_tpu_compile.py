@@ -187,8 +187,12 @@ def _phase_args(eng, state, phase):
                        state["_cloud_cache"], vec, bt)
 
 
-@pytest.mark.parametrize("phase",
-                         ["edge_prefill", "cloud_prefill", "draft", "verify"])
+_PHASE_IMPL = {"edge_prefill": "edge_prefill_impl",
+               "cloud_prefill": "cloud_prefill_impl",
+               "draft": "spec_draft_impl", "verify": "verify_impl"}
+
+
+@pytest.mark.parametrize("phase", list(_PHASE_IMPL))
 def test_tp_engine_phase_compiles_without_gathering_pool(tp_engine, phase,
                                                          monkeypatch):
     # every phase of a mesh engine, edge ones included, runs the compiled
@@ -201,6 +205,10 @@ def test_tp_engine_phase_compiles_without_gathering_pool(tp_engine, phase,
     compiled = fn.lower(*args).compile()
     _assert_kernel(compiled)
     text = compiled.as_text()
+    # the phase compiles under its method's name, and the kernel's calls
+    # keep the name ``kernel.paged_attn_share`` matches
+    assert text.startswith(f"HloModule jit__{_PHASE_IMPL[phase]}"), phase
+    assert "paged_flash" in text, phase
     for pool in (eng._edge_cache, eng._cloud_cache, eng._draft_cache):
         assert not _pool_gathers(text, pool["k_pages"].shape[1:]), phase
 
