@@ -12,16 +12,35 @@ page index to a physical page, so HBM is allocated on demand instead of
 tile ``[page_size, head_dim]`` is contiguous, which is the block shape
 the TPU lowering accepts (its last two dims equal the array's).
 
-One attention call = one grid cell per (batch row, kv head, logical page):
+One attention call = one grid step per (batch row, block of ``hb`` kv
+heads, logical page):
 
-  grid = (B, n_kv, pages_per_seq), pages innermost ("arbitrary" — the
-  online-softmax state m/l/acc lives in VMEM scratch across the page axis)
+  grid = (B, n_kv // hb, pages_per_seq), pages innermost ("arbitrary" —
+  the online-softmax state m/l/acc lives in VMEM scratch across the
+  page axis)
+
+The ``hb`` heads of one page are contiguous, so one step DMAs them as a
+single ``(1, hb, page_size, hd)`` block (64 KiB
+for K at 32 heads, page 16, hd 128).  ``hb`` follows from the shape,
+not from a setting (``_heads_per_step``): the largest divisor of the
+local ``n_kv`` whose ``hb · S · group`` rows of the q, acc, m and l
+tiles stay within ``_VMEM_ROWS`` (2048).  Decode and verify (S ≤ 8)
+fold every head (32 for deepseek-7b, 10 for phi3-medium-14b); a
+128-token prefill bucket takes 16 of 32, a 512-token one 4.  Under ``shard_map`` the rule
+sees the shard's own ``n_kv``.
 
 The block table, per-row KV lengths, and per-row *query start positions*
 ride in scalar-prefetch SMEM so the K/V BlockSpec index maps can redirect
-the page DMA:
+the page DMA, and stop it past a row's last live page
+``last = (max(lengths[b], 1) - 1) // page_size``:
 
-  index_map = lambda b, h, p, bt, ln, qs: (bt[b, p], h, 0, 0)
+  index_map = lambda b, h, p, bt, ln, qs: (bt[b, min(p, last)], h, 0, 0)
+
+A step past ``last`` keeps the block index of the step before it, so
+the pipeline issues no DMA, and its body (under ``p * page_size <
+lengths[b]``) does not run: such a page would have added exactly
+``w = 0`` and ``alpha = exp(0) = 1``, so skipping it changes no bit of
+the result.  Init at ``p == 0`` and emit at the last step stay put.
 
 The q tile carries all S query rows of the block (S=1 for plain decode):
 query i of row b sits at absolute position ``q_start[b] + i`` and may
@@ -39,13 +58,16 @@ attend KV positions ``<= q_start[b] + i`` that are also ``< lengths[b]``
   prompts attend their just-written pages directly, so prefill and
   decode share one read path (and one set of INT8 scales).
 
-INT8 K/V are dequantized *inside* the QK/AV loops — per-(layer, kv-head)
-symmetric scales (optionally calibrated per slot, so shaped [B, n_kv])
-sit whole in SMEM, are read as ``ks_ref[b, h]`` and multiply the page
-tile right after load, so the MXU sees
-f32 while HBM only ever streams 1 B/elem.  GQA runs grouped: the q heads
-sharing a kv head form the sublane dim of the score tile, and a q-block
-of S tokens stacks to an (S·group, hd) tile.
+INT8 K/V are dequantized *inside* the QK/AV loops: the page tile is
+converted to f32 on load, the per-(row, kv-head) K scale (with
+1/sqrt(hd)) multiplies the scores and the V scale the output at emit —
+exact, since each is constant over a (row, head)'s keys.  The scales
+(per (layer, kv-head), optionally calibrated per slot, so shaped
+[B, n_kv]) arrive as ``(1, hb, 1, 1)`` VMEM blocks of [B, n_kv, 1, 1].
+HBM only ever streams 1 B/elem.  GQA runs grouped: the q heads sharing
+a kv head form the sublane dim of the score tile, a q-block of S tokens
+stacks to an (S·group, hd) tile per head, and QK and AV are contractions
+batched over the ``hb`` heads.
 
 Off-TPU, for tests only, there are two stand-ins: ``interpret=True``
 runs the very same kernel through the Pallas interpreter (the parity
@@ -54,13 +76,20 @@ tests), while the serving engines default to
 of identical math.  ``paged_attention`` / ``paged_multiquery_attention``
 dispatch.
 
-VMEM residency per grid cell (defaults, page_size=64, hd=128, group=8,
-S=8):
-  K page  int8 [page_size, hd]   8 KiB      m, l  f32 [S·group, 1]
-  V page  int8 [page_size, hd]   8 KiB      acc   f32 [S·group, hd] 32 KiB
-all « 16 MiB; on real TPU prefer page_size a multiple of 32 (int8
-sublane) and S·group padded to 8 — the interpret/ref paths accept any
-size.
+VMEM residency per grid step (hd = 128, rows = hb · S · group ≤ 2048;
+the last dim pads to 128 lanes):
+  K, V pages  int8 [hb, page_size, hd] x2, double-buffered: 64 KiB each
+                at hb = 32, page 16
+  q, out      [hb, S·G, hd], double-buffered      ≤ 1 MiB each (f32)
+  acc         f32 [hb, S·G, hd]                   ≤ 1 MiB
+  m, l        f32 [hb, S·G, 1]                    ≤ 1 MiB each (padded)
+  scores      f32 [hb, S·G, page_size]            ≤ 1 MiB per temporary
+On a v5e chip, 4096 rows ran with bf16 queries but ran out of VMEM with
+the engines' f32 queries (a described v5e compiles both, so the
+compile tests cannot see that edge); 2048 f32 rows hold less than the
+4096 bf16 rows that ran.  On real TPU prefer page_size a multiple of
+32 (int8 sublane) and S·group padded to 8 — the interpret/ref paths
+accept any size.
 
 Tensor-parallel: ``paged_flash_mq_sharded``/``paged_flash_decode_sharded``
 run the kernel inside ``shard_map`` over a mesh.  When ``n_kv`` divides
@@ -100,12 +129,13 @@ _DEFAULT_IMPL = "auto"
 
 
 def _kernel(bt_ref, len_ref, qs_ref,    # scalar-prefetch: table, lens, q0
-            q_ref, k_ref, v_ref,        # [1,1,S·G,hd], [1,1,P,hd], [1,1,P,hd]
-            ks_ref, vs_ref,             # [B, n_kv] SMEM per-(row, kv-head) scales
-            o_ref,                      # [1,1,S·G,hd]
+            q_ref, k_ref, v_ref,        # [1,hb,S·G,hd], [1,hb,P,hd] x2
+            ks_ref, vs_ref,             # [1,hb,1,1] per-(row, kv-head) scales
+            o_ref,                      # [1,hb,S·G,hd]
             m_ref, l_ref, acc_ref,      # scratch: online-softmax state
             *, page_size: int, group: int, sm_scale: float):
-    b, h, p = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    b, p = pl.program_id(0), pl.program_id(2)
+    length = len_ref[b]
 
     @pl.when(p == 0)
     def _init():
@@ -113,38 +143,59 @@ def _kernel(bt_ref, len_ref, qs_ref,    # scalar-prefetch: table, lens, q0
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # dequant on load: HBM streamed the page at 1 B/elem; the scale is a
-    # scalar broadcast fused into the VPU convert
-    k = k_ref[0, 0].astype(jnp.float32) * ks_ref[b, h]         # [P, hd]
-    v = v_ref[0, 0].astype(jnp.float32) * vs_ref[b, h]
-    q = q_ref[0, 0].astype(jnp.float32) * sm_scale             # [S·G, hd]
+    # pages past the row's last live one: their K/V block index is the
+    # last live page's (no DMA), and this body, which would add exactly
+    # w = 0 and alpha = 1, does not run
+    @pl.when(p * page_size < length)
+    def _step():
+        # HBM streamed the pages at 1 B/elem; the per-(row, head) K scale
+        # and 1/sqrt(hd) multiply the [hb, S·G, P] scores instead of the
+        # [hb, P, hd] tile, the V scale waits for _emit (both exact: each
+        # is constant over a (row, head)'s keys)
+        q = q_ref[0].astype(jnp.float32)                      # [hb, S·G, hd]
+        k = k_ref[0].astype(jnp.float32)                      # [hb, P, hd]
+        v = v_ref[0].astype(jnp.float32)
+        s = jnp.einsum("hqd,hkd->hqk", q, k,
+                       preferred_element_type=jnp.float32) \
+            * (ks_ref[0] * sm_scale)                          # [hb, S·G, P]
+        shape = s.shape
+        pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+        # row r of a head's tile is query token r // group at absolute
+        # position q_start + r // group: intra-block causality + the KV
+        # length bound
+        qpos = qs_ref[b] + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1) // group
+        valid = jnp.logical_and(pos <= qpos, pos < length)
+        s = jnp.where(valid, s, _MASKED)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # [S·G, P]
-    sg = q.shape[0]
-    pos = p * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (sg, page_size), 1)
-    # row r of the tile is query token r // group at absolute position
-    # q_start + r // group: intra-block causality + the KV length bound
-    qpos = qs_ref[b] + jax.lax.broadcasted_iota(
-        jnp.int32, (sg, page_size), 0) // group
-    valid = jnp.logical_and(pos <= qpos, pos < len_ref[b])      # [S·G, P]
-    s = jnp.where(valid, s, _MASKED)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    # explicit re-mask: on an all-masked page exp(s - m) would be exp(0)
-    w = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        w, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        m_prev = m_ref[...]                                   # [hb, S·G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # explicit re-mask: on an all-masked row exp(s - m) would be exp(0)
+        w = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(w, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
+            "hqk,hkd->hqd", w, v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
     @pl.when(p == pl.num_programs(2) - 1)
     def _emit():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] * vs_ref[0] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+# rows (kv heads x query rows) of one grid step's q, acc, m and l tiles
+# that fit the scoped VMEM next to the K/V pages and the score
+# temporaries at f32 queries, hd 128 (module docstring: residency)
+_VMEM_ROWS = 2048
+
+
+def _heads_per_step(n_kv: int, rows: int) -> int:
+    """kv heads one grid step carries: the largest divisor of ``n_kv``
+    whose ``rows``-row tiles (S·group each) stay within ``_VMEM_ROWS``,
+    and at least one."""
+    return max(d for d in range(1, n_kv + 1)
+               if n_kv % d == 0 and (d == 1 or d * rows <= _VMEM_ROWS))
 
 
 def _norm_scales(scale: Optional[jax.Array], batch: int,
@@ -159,6 +210,64 @@ def _norm_scales(scale: Optional[jax.Array], batch: int,
     if scale.ndim == 1:
         scale = jnp.broadcast_to(scale[None], (batch, n_kv))
     return scale
+
+
+def _flash_mq(q, k_pages, v_pages, block_tables, lengths, q_start,
+              k_scale, v_scale, *, hb: int, interpret: bool) -> jax.Array:
+    """``paged_flash_mq`` with ``hb`` kv heads a grid step."""
+    b, s, n_heads, hd = q.shape
+    _, n_kv, page_size, _ = k_pages.shape
+    pages_per_seq = block_tables.shape[1]
+    group = n_heads // n_kv
+    assert group * n_kv == n_heads, (n_heads, n_kv)
+    assert n_kv % hb == 0, (n_kv, hb)
+
+    # [B, n_kv, S·group, hd]: the q heads sharing a kv head — for every
+    # query token of the block — form the sublane dim of one head's tile
+    qg = q.reshape(b, s, n_kv, group, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, n_kv, s * group, hd)
+    ks = _norm_scales(k_scale, b, n_kv).reshape(b, n_kv, 1, 1)
+    vs = _norm_scales(v_scale, b, n_kv).reshape(b, n_kv, 1, 1)
+
+    def row_map(b_, h, p, bt, ln, qs):
+        return b_, h, 0, 0
+
+    def page_map(b_, h, p, bt, ln, qs):
+        # clamp to the row's last live page: a dead step keeps the block
+        # index of the step before it, so the pipeline issues no DMA
+        last = jax.lax.div(jnp.maximum(ln[b_], 1) - 1, page_size)
+        return bt[b_, jnp.minimum(p, last)], h, 0, 0
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, n_kv // hb, pages_per_seq),
+        in_specs=[
+            pl.BlockSpec((1, hb, s * group, hd), row_map),
+            pl.BlockSpec((1, hb, page_size, hd), page_map),
+            pl.BlockSpec((1, hb, page_size, hd), page_map),
+            pl.BlockSpec((1, hb, 1, 1), row_map),
+            pl.BlockSpec((1, hb, 1, 1), row_map),
+        ],
+        out_specs=pl.BlockSpec((1, hb, s * group, hd), row_map),
+        scratch_shapes=[
+            pltpu.VMEM((hb, s * group, 1), jnp.float32),   # running max
+            pltpu.VMEM((hb, s * group, 1), jnp.float32),   # running denom
+            pltpu.VMEM((hb, s * group, hd), jnp.float32),  # un-normalized
+        ],
+    )
+    kernel = functools.partial(_kernel, page_size=page_size, group=group,
+                               sm_scale=1.0 / math.sqrt(hd))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(block_tables, lengths, q_start.astype(jnp.int32), qg, k_pages,
+      v_pages, ks, vs)
+    return out.reshape(b, n_kv, s, group, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, s, n_heads, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -176,55 +285,11 @@ def paged_flash_mq(
 ) -> jax.Array:
     """Flash attention of an S-query block over the paged cache →
     [B, S, n_heads, hd] (query i attends positions <= q_start + i)."""
-    b, s, n_heads, hd = q.shape
-    _, n_kv, page_size, _ = k_pages.shape
-    pages_per_seq = block_tables.shape[1]
-    group = n_heads // n_kv
-    assert group * n_kv == n_heads, (n_heads, n_kv)
-
-    # [B, n_kv, S·group, hd]: the q heads sharing a kv head — for every
-    # query token of the block — form the sublane dim of one tile
-    qg = q.reshape(b, s, n_kv, group, hd).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, n_kv, s * group, hd)
-    ks = _norm_scales(k_scale, b, n_kv)
-    vs = _norm_scales(v_scale, b, n_kv)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, n_kv, pages_per_seq),
-        in_specs=[
-            pl.BlockSpec((1, 1, s * group, hd),
-                         lambda b_, h, p, bt, ln, qs: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, hd),
-                         lambda b_, h, p, bt, ln, qs: (bt[b_, p], h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, hd),
-                         lambda b_, h, p, bt, ln, qs: (bt[b_, p], h, 0, 0)),
-            # whole [B, n_kv] scale arrays: a (1, 1) SMEM block is refused
-            # by the TPU lowering
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1, s * group, hd),
-                               lambda b_, h, p, bt, ln, qs: (b_, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((s * group, 1), jnp.float32),    # running max
-            pltpu.VMEM((s * group, 1), jnp.float32),    # running denominator
-            pltpu.VMEM((s * group, hd), jnp.float32),   # un-normalized out
-        ],
-    )
-    kernel = functools.partial(_kernel, page_size=page_size, group=group,
-                               sm_scale=1.0 / math.sqrt(hd))
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(block_tables, lengths, q_start.astype(jnp.int32), qg, k_pages,
-      v_pages, ks, vs)
-    return out.reshape(b, n_kv, s, group, hd).transpose(0, 2, 1, 3, 4) \
-        .reshape(b, s, n_heads, hd)
+    _, s, n_heads, _ = q.shape
+    n_kv = k_pages.shape[1]
+    hb = _heads_per_step(n_kv, s * (n_heads // n_kv))
+    return _flash_mq(q, k_pages, v_pages, block_tables, lengths, q_start,
+                     k_scale, v_scale, hb=hb, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -297,11 +297,17 @@ def page_visits(pos: Sequence[int], k: int, rows: int, width: int,
     """(Row, page) grid steps of one speculative round's paged-attention
     calls, and how many of them hold a live key.  The kernel's grid runs
     all ``rows`` of the block table over its ``width`` pages
-    (``kernels.paged_attention``); a live row holds keys only in the
-    pages below its KV length.  ``pos``: each live row's committed
-    length less one.  Draft pass i < k makes ``draft_calls`` calls that
-    read ``pos + i + 1`` keys, the verify ``verify_calls`` that read
-    ``pos + k``."""
+    (``kernels.paged_attention``), one step carrying ``hb`` kv heads
+    (every head at decode and verify widths), so a step here has no
+    head factor.  A live row holds keys only in its pages below its KV
+    length, ``ceil(keys / page_size)`` of them: the kernel's own rule,
+    ``p <= (max(length, 1) - 1) // page_size``.  The kernel issues no
+    DMA and runs no math on the other steps, so visited − live counts
+    the steps it skips, less those of idle rows, which are counted as
+    visited but walk the reserved dump page up to a stale length.
+    ``pos``: each live row's committed length less one.  Draft pass
+    i < k makes ``draft_calls`` calls that read ``pos + i + 1`` keys,
+    the verify ``verify_calls`` that read ``pos + k``."""
     keys = np.minimum(np.asarray(pos)[:, None] + np.arange(1, k + 1),
                       max_len)
     pages = -(-keys // page_size)

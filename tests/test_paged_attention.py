@@ -4,13 +4,18 @@ Covers: Pallas kernel (interpret) vs XLA oracle parity, paged INT8-KV
 decode tracking dense fp greedy tokens on a tiny LM, allocator
 invariants (no double allocation, reclamation on retire, block-table
 bounds), and the bucketed-prefill compile bound."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.paged_attention import (paged_attention_ref,
-                                           paged_flash_decode)
+from repro.kernels import paged_attention as PA
+from repro.kernels.paged_attention import (paged_attention_mq_ref,
+                                           paged_attention_ref,
+                                           paged_flash_decode,
+                                           paged_flash_mq)
 from repro.models import transformer as TF
 from repro.models.transformer import LMConfig, init_lm
 from repro.serve.engine import (CollaborativeServingEngine, PageAllocator,
@@ -61,14 +66,74 @@ def _rand_paged(seed, *, b=3, n_heads=8, n_kv=4, hd=16, page=8, n_pages=14,
     return q, kp, vp, bt, lens, ks, vs
 
 
+# rows of length 1, ending on a page boundary and mid-page; a table of 6
+# pages of 8, of which at most 4 are live
+_LENS = (1, 8, 13, 29)
+_WIDTH = 6
+
+
+def _block_case(seed, *, s, n_heads, n_kv, int8):
+    """An S-query block over ``_LENS``: queries end at each row's last
+    key (q_start = length - S, at least 0, so short rows hold query rows
+    past their length, as a padded prefill bucket does)."""
+    _, kp, vp, bt, _, ks, vs = _rand_paged(
+        seed, b=len(_LENS), n_heads=n_heads, n_kv=n_kv, n_pages=26,
+        pages_per=_WIDTH, int8=int8)
+    rng = np.random.RandomState(seed + 1)
+    q = jnp.asarray(rng.randn(len(_LENS), s, n_heads, 16).astype(np.float32))
+    lens = jnp.asarray(_LENS, jnp.int32)
+    return q, kp, vp, bt, lens, jnp.maximum(lens - s, 0), ks, vs
+
+
+def _run_kernel(hb, q, kp, vp, bt, lens, q0, ks, vs):
+    """The kernel in interpret mode: decode through ``paged_flash_decode``
+    at S=1, ``paged_flash_mq`` otherwise, or ``hb`` heads a step forced."""
+    if hb is not None:
+        fn = jax.jit(functools.partial(PA._flash_mq, hb=hb, interpret=True))
+        return fn(q, kp, vp, bt, lens, q0, ks, vs)
+    if q.shape[1] == 1:
+        return paged_flash_decode(q[:, 0], kp, vp, bt, lens, ks, vs,
+                                  interpret=True)[:, None]
+    return paged_flash_mq(q, kp, vp, bt, lens, q0, ks, vs, interpret=True)
+
+
 @pytest.mark.parametrize("int8", [True, False])
-def test_kernel_matches_ref(int8):
+@pytest.mark.parametrize("n_heads,n_kv,hb", [
+    (8, 4, None), (8, 8, None), (8, 2, None),     # every head in one step
+    (8, 8, 2), (8, 2, 1)])                        # forced below n_kv
+@pytest.mark.parametrize("s", [1, 4, 16])
+def test_kernel_matches_ref(s, n_heads, n_kv, hb, int8):
     """Pallas online-softmax over block-table pages == gather oracle."""
-    q, kp, vp, bt, lens, ks, vs = _rand_paged(0, int8=int8)
-    ref = paged_attention_ref(q, kp, vp, bt, lens, ks, vs)
-    out = paged_flash_decode(q, kp, vp, bt, lens, ks, vs, interpret=True)
+    args = _block_case(0, s=s, n_heads=n_heads, n_kv=n_kv, int8=int8)
+    if hb is None:
+        assert PA._heads_per_step(n_kv, s * n_heads // n_kv) == n_kv
+    ref = paged_attention_mq_ref(*args)
+    out = _run_kernel(hb, *args)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("s", [1, 4, 16])
+def test_kernel_never_reads_dead_pages(s):
+    """Every page that no row reaches below its length is NaN: the
+    output stays finite and equal to the reference on the clean pool,
+    so the kernel neither DMAs nor multiplies a page past a row's last
+    live one (``0 * NaN`` would poison the AV sum)."""
+    q, kp, vp, bt, lens, q0, ks, vs = _block_case(
+        2, s=s, n_heads=8, n_kv=2, int8=False)
+    ref = paged_attention_mq_ref(q, kp, vp, bt, lens, q0)
+    table = np.asarray(bt)
+    live = {int(table[r, p]) for r, n in enumerate(_LENS)
+            for p in range(-(-n // kp.shape[2]))}
+    dead = np.array([pg not in live for pg in range(kp.shape[0])])
+    assert dead[table].any()               # the table points at dead pages
+    kp2, vp2 = np.asarray(kp).copy(), np.asarray(vp).copy()
+    kp2[dead] = np.nan
+    vp2[dead] = np.nan
+    out = np.asarray(_run_kernel(None, q, jnp.asarray(kp2), jnp.asarray(vp2),
+                                 bt, lens, q0, ks, vs))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, np.asarray(ref), rtol=1e-5, atol=1e-5)
 
 
 def test_kernel_respects_lengths_and_table():
@@ -111,8 +176,6 @@ def test_ref_matches_dense_sdpa():
 def test_kernel_through_model_stack(params):
     """Force the Pallas kernel (interpret) through attention/run_blocks
     and compare against the default XLA-ref dispatch."""
-    from repro.kernels import paged_attention as PA
-
     prompts = _prompts(2, plen=7, seed=3)
     ref_eng = ServingEngine(params, CFG, max_batch=2, max_len=32,
                             paged=True, page_size=8)

@@ -22,7 +22,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.int8_matmul import int8_matmul_pallas
 from repro.kernels.ops import _pick_block
-from repro.kernels.paged_attention import (paged_flash_decode,
+from repro.kernels.paged_attention import (_heads_per_step,
+                                           paged_flash_decode,
                                            paged_flash_mq,
                                            paged_flash_mq_sharded)
 
@@ -66,12 +67,27 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("s", [1, 8, 128])    # decode, verify, prefill
+# decode, verify, prefill, and the chat mix's largest prefill bucket
+@pytest.mark.parametrize("s", [1, 8, 128, 512])
 @pytest.mark.parametrize("page", [16, 64])
 @pytest.mark.parametrize("n_heads,n_kv", [(32, 32), (40, 10), (32, 4)])
 def test_paged_flash_mq_compiles(one_chip, n_heads, n_kv, page, s):
     args = _paged_args(one_chip, s=s, n_heads=n_heads, n_kv=n_kv, page=page)
     _assert_kernel(paged_flash_mq.lower(*args).compile())
+
+
+def test_heads_per_step_rule():
+    # decode and verify fold every kv head into one grid step
+    # (deepseek-7b: 32 heads, group 1; phi3-medium-14b: 10 kv, group 4);
+    # prefill buckets take fewer, within the VMEM row budget
+    for s in (1, 4, 8):
+        assert _heads_per_step(32, s) == 32
+        assert _heads_per_step(10, s * 4) == 10
+    assert _heads_per_step(32, 128) == 16
+    assert _heads_per_step(32, 512) == 4
+    assert _heads_per_step(10, 128 * 4) == 2
+    assert _heads_per_step(10, 512 * 4) == 1
+    assert _heads_per_step(4, 512 * 8) == 1
 
 
 def test_paged_flash_decode_compiles(one_chip):
