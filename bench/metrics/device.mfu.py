@@ -1,4 +1,5 @@
-"""Model FLOPs of the served tokens (``bench/flops.py``) over the
+"""Model FLOPs of the served tokens (``request_flops`` of the model's
+``bench/archs/<arch>.py``) over the
 window's host-clock seconds, as a share of the chips' bf16 peak
 (``bench/peaks.json``).  Moves ``tokens_per_s``."""
 

@@ -1,12 +1,16 @@
 """Plain float32 reference of the collaborative decoder, and the check.
 
-What the configuration states is served: a dense RoPE/GQA/SwiGLU decoder
-whose first ``cut + 1`` blocks run on the edge on an INT8 lattice, whose
-boundary activation crosses the link quantized, and whose KV caches hold
-INT8 entries.  This module computes that model in float32, every matmul
-at ``HIGHEST`` precision, in plain ``jax.numpy`` with no kernel, page
-pool or batching, and imports nothing of the program.  The lattice it
-applies is the deployment's, as stated:
+What the configuration states is served: a decoder whose first
+``cut + 1`` blocks run on the edge on an INT8 lattice, whose boundary
+activation crosses the link quantized, and whose KV caches hold INT8
+entries.  This module computes that model in float32, every matmul at
+``HIGHEST`` precision, in plain ``jax.numpy`` with no kernel, page pool
+or batching, and imports nothing of the program.  The math of one block
+is the architecture's (``block`` of ``bench/archs/<arch>.py``); this
+module runs the stack of blocks, the boundary, the final norm and the
+head, and hands each block call its lattice (``OnLattice``) and its KV
+cache (``_kv_protocol``).  The lattice it applies is the deployment's,
+as stated:
 
 * edge weights: asymmetric min/max INT8 per output channel, per layer;
 * edge matmul inputs: asymmetric min/max INT8, one range per request over
@@ -51,8 +55,6 @@ import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
-
-from bench.weights import Model
 
 HI = None   # jax.lax.Precision.HIGHEST, bound on first use
 
@@ -130,10 +132,12 @@ def _fq(x, scale, zp, dt, bits):
 
 
 def _fq_weight(w, dt, bits):
-    """Per output channel (last axis), range over the input axis."""
+    """Per output channel (last axis), range over the input axis (the
+    one before it); any axes before that are stacked weights, each with
+    ranges of its own."""
     _, jnp = _jax()
-    scale, zp = _affine(jnp.min(w, axis=0), jnp.max(w, axis=0), dt, bits)
-    return _fq(w, scale[None], zp[None], dt, bits)
+    scale, zp = _affine(jnp.min(w, axis=-2), jnp.max(w, axis=-2), dt, bits)
+    return _fq(w, scale[..., None, :], zp[..., None, :], dt, bits)
 
 
 def _fq_act(x, row_mask, dt, bits):
@@ -171,9 +175,9 @@ def _kv_q(k, scale, dt, bits, *, prefill):
     return jnp.clip(jnp.round(r), -qmax, qmax) * scale[None, :, None]
 
 
-# -- the model ---------------------------------------------------------------
+# -- math every architecture uses ------------------------------------------
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     _, jnp = _jax()
     half = x.shape[-1] // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
@@ -183,96 +187,98 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
 
 
-def _rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps):
     _, jnp = _jax()
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x / jnp.sqrt(var + eps) * scale
 
 
-def _mm(x, w):
+def mm(x, w):
     _, jnp = _jax()
     return jnp.einsum("sd,df->sf", x, w, precision=HI)
 
 
-def _block(m: Model, bp, x, pos, *, lat, kv_bits, row_mask, kv_prev,
-           kv_valid, kv_scale, prefill_valid):
-    """One decoder block over ``x`` [S, D] at positions ``pos`` [S].
+# -- what a block is handed --------------------------------------------------
 
-    ``lat``: (dt, bits) when this block runs on a weight and activation
-    lattice, else None; ``kv_bits``: its KV cache's lattice.  Prefill: ``kv_prev`` is None, keys are this call's rows, valid
-    where ``prefill_valid``, and the KV scale is calibrated from them.
-    Decode: ``kv_prev`` = (k, v) [P, H, D] of the prompt, valid where
-    ``kv_valid``, with their ``kv_scale``; this call's keys attend
-    causally among themselves.  Returns (x, (k, v) dequantized, scale)."""
+class OnLattice:
+    """The weight and activation lattice one block call runs on, for the
+    architecture's block to apply: ``weight`` to each of its weights
+    [..., in, out] (asymmetric, one range per output channel over the
+    input axis and one more per index of any leading axis: an expert
+    stack [E, in, out] gets one per expert and output channel, and
+    [E * in, out] one per output channel over every expert), ``act`` to
+    each matmul input [S, F] (one range over the rows of ``row_mask``;
+    ``row_mask`` None: one per row).  ``bits`` None: no lattice, both
+    are the identity."""
+
+    def __init__(self, dt, bits: Optional[int], row_mask):
+        self.dt, self.bits, self.row_mask = dt, bits, row_mask
+
+    def weight(self, w):
+        return w if self.bits is None else _fq_weight(w, self.dt, self.bits)
+
+    def act(self, h):
+        if self.bits is None:
+            return h
+        return _fq_act(h, self.row_mask, self.dt, self.bits)
+
+
+def _kv_protocol(dt, bits, pos, *, prefill_valid, kv_prev, kv_valid,
+                 kv_scale):
+    """The KV cache of one block call, for the architecture's block to
+    apply to its keys and values [S, H, D] at positions ``pos`` [S]:
+    ``kv(k, v)`` puts them on the KV lattice and returns the keys and
+    values the call's rows attend over [L, H, D], which of them each row
+    sees ([S, L] bool, causal), and the state the block returns.
+
+    Prefill (``kv_prev`` None): keys are this call's rows, valid where
+    ``prefill_valid``, and the KV scale is calibrated from them.  Decode:
+    ``kv_prev`` = (k, v) [P, H, D] of the prompt, valid where
+    ``kv_valid``, with their ``kv_scale``; this call's keys reuse it,
+    saturate, and attend causally among themselves.  The state is the
+    dequantized (k, v) and their scales."""
+    _, jnp = _jax()
+
+    def kv(k, v):
+        if kv_prev is None:
+            ks = _kv_scale(k, prefill_valid, dt, bits)
+            vs = _kv_scale(v, prefill_valid, dt, bits)
+            kd = _kv_q(k, ks, dt, bits, prefill=True)
+            vd = _kv_q(v, vs, dt, bits, prefill=True)
+            keys, vals = kd, vd
+            valid = prefill_valid[None, :] & (pos[None, :] <= pos[:, None])
+        else:
+            ks, vs = kv_scale
+            kd = _kv_q(k, ks, dt, bits, prefill=False)
+            vd = _kv_q(v, vs, dt, bits, prefill=False)
+            keys = jnp.concatenate([kv_prev[0], kd], axis=0)
+            vals = jnp.concatenate([kv_prev[1], vd], axis=0)
+            s = k.shape[0]
+            own = jnp.arange(s)
+            valid = jnp.concatenate(
+                [jnp.broadcast_to(kv_valid[None, :], (s, kv_valid.shape[0])),
+                 own[None, :] <= own[:, None]], axis=1)
+        return keys, vals, valid, ((kd, vd), (ks, vs))
+
+    return kv
+
+
+def _scan(block, m, blocks, x, pos, bits, kv_bits, *, row_mask,
+          prefill_valid=None, kv_prev=None, kv_valid=None, kv_scale=None):
+    """Run a stack of the architecture's ``block``s in order, all on a
+    lattice of ``bits`` (static; None: on none), each block's weights in
+    float32.  Returns x and, stacked per block, the dequantized (k, v)
+    and their scales."""
     jax, jnp = _jax()
     dt = jnp.dtype(m.dtype)
-    up = functools.partial(jax.tree_util.tree_map,
-                           lambda a: a.astype(jnp.float32))
-    bp = up(bp)
-    if lat is not None:
-        bp = jax.tree_util.tree_map(
-            lambda a: _fq_weight(a, *lat) if a.ndim == 2 else a, bp)
-
-        def qa(h):
-            return _fq_act(h, row_mask, *lat)
-    else:
-        def qa(h):
-            return h
-    s = x.shape[0]
-    nh, nkv, hd = m.num_attention_heads, m.num_key_value_heads, m.head_dim
-    h = qa(_rmsnorm(x, bp["ln1"]["scale"], m.rms_norm_eps))
-    q = _mm(h, bp["attn"]["wq"]["w"]).reshape(s, nh, hd)
-    k = _mm(h, bp["attn"]["wk"]["w"]).reshape(s, nkv, hd)
-    v = _mm(h, bp["attn"]["wv"]["w"]).reshape(s, nkv, hd)
-    q = _rope(q, pos, m.rope_theta)
-    k = _rope(k, pos, m.rope_theta)
-    if kv_prev is None:
-        ks = _kv_scale(k, prefill_valid, dt, kv_bits)
-        vs = _kv_scale(v, prefill_valid, dt, kv_bits)
-        kd = _kv_q(k, ks, dt, kv_bits, prefill=True)
-        vd = _kv_q(v, vs, dt, kv_bits, prefill=True)
-        keys, vals = kd, vd
-        kpos = pos
-        valid = prefill_valid[None, :] & (kpos[None, :] <= pos[:, None])
-    else:
-        ks, vs = kv_scale
-        kd = _kv_q(k, ks, dt, kv_bits, prefill=False)
-        vd = _kv_q(v, vs, dt, kv_bits, prefill=False)
-        keys = jnp.concatenate([kv_prev[0], kd], axis=0)
-        vals = jnp.concatenate([kv_prev[1], vd], axis=0)
-        own = jnp.arange(s)
-        valid = jnp.concatenate(
-            [jnp.broadcast_to(kv_valid[None, :], (s, kv_valid.shape[0])),
-             own[None, :] <= own[:, None]], axis=1)
-    group = nh // nkv
-    qg = q.reshape(s, nkv, group, hd)
-    logits = jnp.einsum("sngd,lnd->ngsl", qg, keys, precision=HI) \
-        / np.sqrt(hd)
-    logits = jnp.where(valid[None, None], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("ngsl,lnd->sngd", p, vals, precision=HI).reshape(s, nh * hd)
-    x = x + _mm(qa(o), bp["attn"]["wo"]["w"])
-    z = qa(_rmsnorm(x, bp["ln2"]["scale"], m.rms_norm_eps))
-    g = _mm(z, bp["mlp"]["wi"]["w"]) * jax.nn.silu(_mm(z, bp["mlp"]["wg"]["w"]))
-    x = x + _mm(qa(g), bp["mlp"]["wo"]["w"])
-    return x, (kd, vd), (ks, vs)
-
-
-def _scan(m, blocks, x, pos, bits, kv_bits, *, row_mask, prefill_valid=None,
-          kv_prev=None, kv_valid=None, kv_scale=None):
-    """Run a stack of blocks in order, all on a lattice of ``bits``
-    (static; None: on none).  Returns x and, stacked per block, the
-    dequantized (k, v) and their scales."""
-    jax, jnp = _jax()
-    lat = None if bits is None else (jnp.dtype(m.dtype), bits)
+    lat = OnLattice(dt, bits, row_mask)
 
     def body(x, inp):
         bp, prev, sc = inp
-        x, kv, sc = _block(m, bp, x, pos, lat=lat, kv_bits=kv_bits,
-                           row_mask=row_mask,
-                           prefill_valid=prefill_valid, kv_prev=prev,
-                           kv_valid=kv_valid, kv_scale=sc)
-        return x, (kv, sc)
+        bp = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), bp)
+        kv = _kv_protocol(dt, kv_bits, pos, prefill_valid=prefill_valid,
+                          kv_prev=prev, kv_valid=kv_valid, kv_scale=sc)
+        return block(m, bp, x, pos, lat, kv)
 
     x, (kvs, scales) = jax.lax.scan(body, x, (blocks, kv_prev, kv_scale))
     return x, kvs, scales
@@ -285,16 +291,16 @@ def _boundary(h, row_mask, dt, bits):
 def _head(m, params, x, bits):
     jax, jnp = _jax()
     dt = jnp.dtype(m.dtype)
-    x = _rmsnorm(x, params["final_norm"]["scale"].astype(jnp.float32),
-                 m.rms_norm_eps)
+    x = rmsnorm(x, params["final_norm"]["scale"].astype(jnp.float32),
+                m.rms_norm_eps)
     w = params["lm_head"]["w"].astype(jnp.float32)
     if bits is not None:
         w = _fq_weight(w, dt, bits)
         x = _fq_act(x, None, dt, bits)
-    return _mm(x, w)
+    return mm(x, w)
 
 
-def _forward(m: Model, lat: Lattice, params, toks_p, plen, bucket, dec):
+def _forward(block, m, lat: Lattice, params, toks_p, plen, bucket, dec):
     """Logits at every served position of one request: row 0 at the last
     prompt position, row i at prompt length + i - 1 (input ``dec[i-1]``).
     ``toks_p`` [P]: prompt padded with 0; ``dec`` [T]: served tokens but
@@ -315,10 +321,10 @@ def _forward(m: Model, lat: Lattice, params, toks_p, plen, bucket, dec):
 
     # prefill: the edge over the bucket-padded prompt, the boundary, the
     # cloud; the first served token comes from the last prompt position
-    h, e_kv, e_sc = _scan(m, e_blocks, x, pos, eb, kvb,
+    h, e_kv, e_sc = _scan(block, m, e_blocks, x, pos, eb, kvb,
                           row_mask=in_bucket, prefill_valid=real)
     h = _boundary(h, real, dt, lat.boundary_bits)
-    y, c_kv, c_sc = _scan(m, c_blocks, h, pos, cb, kvb,
+    y, c_kv, c_sc = _scan(block, m, c_blocks, h, pos, cb, kvb,
                           row_mask=in_bucket, prefill_valid=real)
     first = _head(m, params, jax.lax.dynamic_slice_in_dim(y, plen - 1, 1),
                   cb)
@@ -328,27 +334,27 @@ def _forward(m: Model, lat: Lattice, params, toks_p, plen, bucket, dec):
     t = dec.shape[0]
     dpos = plen + jnp.arange(t)
     x = emb[dec].astype(jnp.float32)
-    h, _, _ = _scan(m, e_blocks, x, dpos, eb, kvb, row_mask=None,
+    h, _, _ = _scan(block, m, e_blocks, x, dpos, eb, kvb, row_mask=None,
                     kv_prev=e_kv, kv_valid=real, kv_scale=e_sc)
     h = _boundary(h, None, dt, lat.boundary_bits)
-    y, _, _ = _scan(m, c_blocks, h, dpos, cb, kvb, row_mask=None,
+    y, _, _ = _scan(block, m, c_blocks, h, dpos, cb, kvb, row_mask=None,
                     kv_prev=c_kv, kv_valid=real, kv_scale=c_sc)
     rest = _head(m, params, y, cb)
     return jnp.concatenate([first, rest], axis=0)                # [1+T, V]
 
 
 @functools.lru_cache(maxsize=8)
-def _compiled(m: Model, lat: Lattice, controls: Tuple[str, ...]):
+def _compiled(block, m, lat: Lattice, controls: Tuple[str, ...]):
     jax, jnp = _jax()
 
     def gaps(params, toks_p, plen, bucket, dec, served):
-        ref = _forward(m, lat, params, toks_p, plen, bucket, dec)
+        ref = _forward(block, m, lat, params, toks_p, plen, bucket, dec)
         best = jnp.max(ref, axis=-1)
         got = jnp.take_along_axis(ref, served[:, None], axis=-1)[:, 0]
         out = [best - got]
         for name in controls:
-            low = _forward(m, CONTROLS[name](lat), params, toks_p, plen,
-                           bucket, dec)
+            low = _forward(block, m, CONTROLS[name](lat), params, toks_p,
+                           plen, bucket, dec)
             pick = jnp.argmax(low, axis=-1)
             out.append(best - jnp.take_along_axis(ref, pick[:, None],
                                                   axis=-1)[:, 0])
@@ -357,7 +363,7 @@ def _compiled(m: Model, lat: Lattice, controls: Tuple[str, ...]):
     return jax.jit(gaps)
 
 
-def served_gaps(m: Model, lattice: Lattice, params, samples: Sequence[Sample],
+def served_gaps(block, m, lattice: Lattice, params, samples: Sequence[Sample],
                 *, prompt_len: int, served_len: int,
                 controls: Sequence[str] = ()
                 ) -> Tuple[List[np.ndarray], Dict[str, List[np.ndarray]]]:
@@ -366,7 +372,7 @@ def served_gaps(m: Model, lattice: Lattice, params, samples: Sequence[Sample],
     control's first choice.  ``prompt_len``/``served_len`` fix the padded
     shapes, so one compiled program serves every request of a cell."""
     _, jnp = _jax()
-    fn = _compiled(m, lattice, tuple(controls))
+    fn = _compiled(block, m, lattice, tuple(controls))
     prog: List[np.ndarray] = []
     ctrl: Dict[str, List[np.ndarray]] = {c: [] for c in controls}
     for smp in samples:

@@ -4,8 +4,11 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell is made of is found by name from ``BENCHMARK.json``:
-its configuration file, ``bench/traffic/<mix>.json``, and with
-``--trace 1`` one reader per per-layer metric, ``bench/metrics/<name>.py``.
+its configuration file, the architecture file the configuration's
+``arch`` names (``bench/archs/<arch>.py``: model, weights, engine
+config, FLOP count and the reference's block; ``bench/weights.py``),
+``bench/traffic/<mix>.json``, and with ``--trace 1`` one reader per
+per-layer metric, ``bench/metrics/<name>.py``.
 
 One process that starts no children.  It needs the chips the cell asks
 for: without a TPU it exits non-zero and prints no result.  It builds
@@ -34,7 +37,6 @@ T_START = time.perf_counter()
 import argparse                                                # noqa: E402
 import dataclasses                                             # noqa: E402
 import gc                                                      # noqa: E402
-import importlib.util                                          # noqa: E402
 import json                                                    # noqa: E402
 import os                                                      # noqa: E402
 import shutil                                                  # noqa: E402
@@ -52,10 +54,10 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import flops, reference, trace_reduce               # noqa: E402
+from bench import reference, trace_reduce                      # noqa: E402
 from bench.traffic import (Job, Stream, bucket_len, buckets,   # noqa: E402
                            load_mix, max_len_for, seed_sequence)
-from bench.weights import load_config, make_params, model_of   # noqa: E402
+from bench.weights import load_arch, load_config, module_at    # noqa: E402
 
 CACHE_DIR = ".jax_cache"         # inside the checkout, at a fixed path
 
@@ -106,11 +108,7 @@ def load_reader(root: Path, name: str) -> Callable:
     path = Path(root) / "bench" / "metrics" / f"{name}.py"
     if not path.is_file():
         raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return module_at(path).read
 
 
 def cell_metrics(bench: Dict, cell: str, kind: str) -> List[Dict]:
@@ -141,6 +139,7 @@ class Window:
     peaks: Dict[str, Any]
     n_devices: int
     trace: Optional[trace_reduce.Reduced] = None
+    arch: Any = None                   # the model's bench/archs/<arch>.py
 
     @property
     def wall_s(self) -> float:
@@ -164,7 +163,8 @@ class Window:
         return self.stats[name]
 
     def flops(self) -> float:
-        return sum(flops.request_flops(self.model, len(j.prompt), len(o))
+        return sum(self.arch.request_flops(self.model, len(j.prompt),
+                                           len(o))
                    for j, o in self.served())
 
 
@@ -217,17 +217,6 @@ class CompileClock:
 
 # -- the run -----------------------------------------------------------------
 
-def lm_config(model, cfg_cls):
-    import jax.numpy as jnp
-    return cfg_cls(name=model.name, n_layers=model.num_hidden_layers,
-                   d_model=model.hidden_size,
-                   n_heads=model.num_attention_heads,
-                   n_kv=model.num_key_value_heads,
-                   d_ff=model.intermediate_size, vocab=model.vocab_size,
-                   rope_base=model.rope_theta, dtype=jnp.dtype(model.dtype),
-                   remat=False)
-
-
 def warm_jobs(mix, stream: Stream, max_len: int, slots: int, page_size: int,
               spec_k: int) -> List[List[Job]]:
     """Calls that compile every program the mix's traffic can run.
@@ -276,7 +265,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     cell = find(bench["workloads"], workload, "workload")
     conf = load_config(root, find(bench["configs"], cell["config"],
                                   "configuration"))
-    model = model_of(conf)
+    arch = load_arch(root, conf)
+    model = arch.model_of(conf)
     mix = load_mix(root, cell["traffic"])
     limits = load_limits(root, workload)
     eng_conf = conf["engine"]
@@ -311,7 +301,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     # cache, so a second run of a cell in a checkout compiles none of them
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     clock = CompileClock(jax)
-    cfg = lm_config(model, LMConfig)
+    cfg = arch.engine_config(model, LMConfig)
     slots, spec_k = int(eng_conf["slots"]), int(eng_conf["spec_k"])
     max_len = max_len_for(mix, spec_k)
     channel = Channel.from_kbps(float(mix.link["bandwidth_kbps"]),
@@ -319,7 +309,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     cut = auto_cut(cfg, channel, mix.median_prompt()) \
         if eng_conf["cut"] == "auto" else int(eng_conf["cut"])
 
-    params = make_params(model, seed)
+    params = arch.make_params(model, seed)
     jax.block_until_ready(params)
     eng = CollaborativeServingEngine(
         params, cfg, cut_layer=cut, channel=channel, max_len=max_len,
@@ -381,7 +371,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
     device["memory_peak_bytes"] = int(peak)
     w = Window(calls=calls, stats={k: s1[k] - s0[k] for k in s1},
                compiles=compiles, vocab=model.vocab_size, model=model,
-               peaks=peaks, n_devices=int(cell["chips"]))
+               peaks=peaks, n_devices=int(cell["chips"]), arch=arch)
     log(f"window: {len(calls)} generate calls, {w.attempted} requests, "
         f"{w.tokens} tokens served, {w.wall_s:.6f} s; {compiles} compiles "
         f"inside it ({clock.secs - cs0:.3f} s, {clock.hits - ch0} cache "
@@ -472,13 +462,13 @@ def check(limits, model, mix, max_len, cut, seed, w: Window,
     picked = sample(w, seed, int(mix.spec["check_tokens"]))
     low: Dict[str, list] = {}
     if picked:
-        params = make_params(model, seed)
+        params = w.arch.make_params(model, seed)
         smp = [reference.Sample(prompt=j.prompt, served=np.asarray(o),
                                 bucket=bucket_len(len(j.prompt), max_len))
                for j, o in picked]
         t0 = time.perf_counter()
         gaps, low = reference.served_gaps(
-            model, reference.Lattice(cut=cut), params, smp,
+            w.arch.block, model, reference.Lattice(cut=cut), params, smp,
             prompt_len=buckets(mix, max_len)[-1],
             served_len=mix.max_output(), controls=controls)
         del params

@@ -1,6 +1,8 @@
 """CPU tests of the benchmark harness: the arithmetic of its metrics, the
-trace reduction, discovery of cells by name, and its refusal to run
-without a TPU.  Nothing here touches a TPU or describes one."""
+trace reduction, discovery of cells and architectures by name, the
+dense architecture's refusal of what it does not serve, and the
+harness's refusal to run without a TPU.  Nothing here touches a TPU or
+describes one."""
 from __future__ import annotations
 
 import dataclasses
@@ -14,12 +16,13 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from bench import flops, reference, run, trace_reduce
+from bench import reference, run, trace_reduce
 from bench.traffic import (Stream, bucket_len, buckets, load_mix,
                            max_len_for, seed_sequence)
-from bench.weights import load_config, model_of
+from bench.weights import load_arch, load_config
 
 ROOT = Path(__file__).resolve().parents[2]
+DEEPSEEK = json.loads((ROOT / "bench/configs/deepseek-7b.json").read_text())
 
 
 # -- end-to-end arithmetic ---------------------------------------------------
@@ -87,18 +90,38 @@ def test_reference_numbers_from_gaps():
 # -- model FLOPs ---------------------------------------------------------------
 
 def test_request_flops_against_a_hand_count():
-    m = dataclasses.replace(
-        model_of(json.loads((ROOT / "bench/configs/deepseek-7b.json")
-                            .read_text())),
-        hidden_size=64, intermediate_size=172, num_attention_heads=4,
-        num_key_value_heads=4, num_hidden_layers=2, vocab_size=256)
+    dense = load_arch(ROOT, DEEPSEEK)
+    m = dense.model_of(dict(
+        DEEPSEEK, hidden_size=64, intermediate_size=172,
+        num_attention_heads=4, num_key_value_heads=4, num_hidden_layers=2,
+        vocab_size=256))
     # block matmuls: q, o 64x64 each; k, v 64x64 each; wi, wg, wo 64x172
     assert m.block_matmul_params() == 4 * 64 * 64 + 3 * 64 * 172 == 49408
     # prompt 5, served 3: positions 0..6 run (the last token is not fed)
     linear = 2 * 49408 * 2 * 7
     attn = 2 * 2 * 4 * 16 * 2 * sum(t + 1 for t in range(7))
     head = 2 * 64 * 256 * 3
-    assert flops.request_flops(m, 5, 3) == linear + attn + head == 1496064
+    assert dense.request_flops(m, 5, 3) == linear + attn + head == 1496064
+
+
+# -- what an architecture serves -------------------------------------------
+
+@pytest.mark.parametrize("key,value", [
+    (None, None), ("num_experts", 64), ("sliding_window", 1024),
+    ("rms_norm_eps", 1e-5), ("hidden_act", "gelu"),
+    ("tie_word_embeddings", True)])
+def test_dense_refuses_what_the_program_would_not_serve_as_stated(key,
+                                                                   value):
+    """deepseek-7b's file loads as it stands; a key the dense file does
+    not serve, or a value of one it reads that the program would not
+    apply, is refused by name before anything is built."""
+    dense = load_arch(ROOT, DEEPSEEK)
+    if key is None:
+        m = dense.model_of(DEEPSEEK)
+        assert (m.head_dim, m.num_hidden_layers) == (128, 7)
+        return
+    with pytest.raises(ValueError, match=key):
+        dense.model_of(dict(DEEPSEEK, **{key: value}))
 
 
 # -- traffic ---------------------------------------------------------------------
@@ -312,7 +335,7 @@ def test_cells_configs_and_mixes_are_found_by_name():
     for cell in bench["workloads"]:
         conf = load_config(ROOT, run.find(bench["configs"], cell["config"],
                                           "configuration"))
-        model_of(conf)
+        load_arch(ROOT, conf).model_of(conf)
         load_mix(ROOT, cell["traffic"])
         assert run.load_limits(ROOT, cell["name"])["max_logit_gap"] > 0
         assert run.cell_metrics(bench, cell["name"], "end_to_end")
@@ -329,6 +352,8 @@ def test_unknown_names_and_missing_files_fail(tmp_path):
         run.load_reader(ROOT, "no.such_metric")
     with pytest.raises(FileNotFoundError):
         load_config(ROOT, {"file": "bench/configs/no-such.json"})
+    with pytest.raises(FileNotFoundError, match="no-such-arch.py"):
+        load_arch(ROOT, {"arch": "no-such-arch"})
     with pytest.raises(FileNotFoundError):
         run.load_limits(ROOT, "no-such.cell")
     with pytest.raises(FileNotFoundError):
@@ -339,17 +364,21 @@ def test_unknown_names_and_missing_files_fail(tmp_path):
 
 
 def test_a_new_cell_needs_only_new_files_and_entries(tmp_path):
-    """A throwaway configuration, mix and per-layer metric are added as
-    new files plus new BENCHMARK.json entries; no existing file of the
-    benchmark is edited, and the harness finds all three by name."""
+    """A throwaway architecture, configuration, mix and per-layer metric
+    are added as new files plus new BENCHMARK.json entries; no existing
+    file of the benchmark is edited, and the harness finds all four by
+    name."""
     shutil.copytree(ROOT / "bench", tmp_path / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
               if p.is_file()}
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     conf = json.loads((ROOT / "bench/configs/deepseek-7b.json").read_text())
-    conf.update(name="throwaway", num_hidden_layers=2)
+    conf.update(name="throwaway", arch="wide", num_hidden_layers=2)
     (tmp_path / "bench/configs/throwaway.json").write_text(json.dumps(conf))
+    (tmp_path / "bench/archs/wide.py").write_text(
+        (ROOT / "bench/archs/dense.py").read_text()
+        + "\n\nKIND = 'wide'\n")
     mix = json.loads((ROOT / "bench/traffic/chat.json").read_text())
     mix.update(wave=4, pool=8)
     (tmp_path / "bench/traffic/burst.json").write_text(json.dumps(mix))
@@ -372,9 +401,12 @@ def test_a_new_cell_needs_only_new_files_and_entries(tmp_path):
 
     b = run.load_benchmark(tmp_path)
     cell = run.find(b["workloads"], "throwaway.burst", "workload")
-    m = model_of(load_config(tmp_path, run.find(b["configs"],
-                                                cell["config"], "c")))
-    assert m.num_hidden_layers == 2
+    conf = load_config(tmp_path, run.find(b["configs"], cell["config"],
+                                          "c"))
+    arch = load_arch(tmp_path, conf)
+    assert arch.KIND == "wide"
+    m = arch.model_of(conf)
+    assert m.num_hidden_layers == 2 and isinstance(m, arch.Model)
     assert load_mix(tmp_path, "burst").wave == 4
     names = [x["name"] for x in run.cell_metrics(b, "throwaway.burst",
                                                  "per_layer")]
